@@ -25,6 +25,7 @@ import os
 import sys
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.telemetry import read_jsonl
 
 ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
@@ -97,6 +98,7 @@ def main() -> None:
                          "the merged BENCH_manifest.json (default: "
                          "records go to the repo root)")
     args = ap.parse_args()
+    enable_compile_cache()
     out_dir = os.path.abspath(args.out_dir) if args.out_dir else None
     if out_dir:
         os.makedirs(out_dir, exist_ok=True)

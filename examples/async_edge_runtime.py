@@ -17,6 +17,7 @@ import argparse
 
 from repro.federation.simulation import FedConfig, Federation
 from repro.federation.topology import make_churn_trace
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime import RuntimeConfig
 
 
@@ -32,6 +33,7 @@ def main():
     ap.add_argument("--churn", action="store_true")
     ap.add_argument("--constrained", type=float, default=0.3)
     args = ap.parse_args()
+    enable_compile_cache()
 
     fed_kw = dict(n_clients=args.clients, n_edges=args.edges, alpha=0.2,
                   poisoned=(2,), total_examples=1500, probe_q=16,

@@ -4,10 +4,15 @@ coherence/trust-weighted cloud fusion, with checkpointing.
 
   PYTHONPATH=src python examples/elsa_federated_finetune.py \
       [--rounds 10] [--clients 20] [--method elsa] [--full] \
-      [--model bert-base|llama3-8b|...] [--backend batched|reference]
+      [--model bert-base|llama3-8b|...] [--backend batched|reference] \
+      [--published] [--seq-len N]
 
 --full uses the paper's 20-client / 4-edge / 8-layer setup (slow on CPU);
 the default is a reduced config that finishes in a few minutes.
+
+--published runs the model at its published widths and depth (BERT-base:
+12 x 768, vocab 30522) instead of the reduced CPU-sized config; meant
+for an accelerator.  --seq-len sets the synthetic sequence length.
 
 --model picks any architecture registered in the SplitModel registry
 (docs/models.md): the paper's "bert-base" encoder by default, or a
@@ -30,6 +35,7 @@ import os
 
 from repro.checkpoint import save
 from repro.federation.simulation import FedConfig, Federation
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -56,8 +62,14 @@ def main():
     ap.add_argument("--tuned", action="store_true",
                     help="convergence stack: clipping, per-group lrs, "
                          "mean-pool readout, FedAdam server step")
+    ap.add_argument("--published", action="store_true",
+                    help="published widths and depth instead of the "
+                         "reduced config (for an accelerator)")
+    ap.add_argument("--seq-len", type=int, default=None,
+                    help="synthetic-task sequence length")
     ap.add_argument("--out", default="runs/elsa_finetune")
     args = ap.parse_args()
+    enable_compile_cache()
 
     # --tuned defaults alpha to the studied 5.0; an explicit --alpha
     # always wins (so the tuned stack can be stressed under any skew)
@@ -74,6 +86,8 @@ def main():
                   local_warmup_steps=4, layers=4, lr=2e-2,
                   t_rounds=1, model=args.model)
     kw["aggregate"] = args.aggregate
+    if args.published:
+        kw.update(reduced=False, layers=None)
     if args.tuned:
         lm = args.model != "bert-base"
         kw.update(clip_norm=1.0, seq_len=32,
@@ -81,6 +95,8 @@ def main():
                   server_opt="fedadam", server_lr=0.03)
         kw.update(dict(lr=0.5, vocab_size=32) if lm
                   else dict(lr=5e-3, head_lr=0.4, pooling="mean"))
+    if args.seq_len is not None:
+        kw["seq_len"] = args.seq_len
     cfg = FedConfig(**kw)
     fed = Federation(cfg, backend=args.backend)
 

@@ -9,9 +9,11 @@ import sys
 sys.path.insert(0, ".")
 
 from benchmarks.bench_privacy import run  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     print("protection            rho    cos     mse     token-id acc")
     rows = run()
     for name, rho, cos, mse, acc in rows:

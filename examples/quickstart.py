@@ -10,6 +10,9 @@ from repro.core.clustering import cluster_clients
 from repro.core.sketch import compress, decompress, make_plan
 from repro.core.splitting import SplitPolicy, splits_for_population
 from repro.core.ssop import apply_ssop, apply_ssop_inverse, make_ssop
+from repro.launch.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 # 1. behavior-aware clustering (Eqs. 4-6 + Stages 1-4) ----------------------
 rng = np.random.default_rng(0)

@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ASSIGNED, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import make_serve_step
 from repro.models import zoo
 from repro.models.params import init_tree
@@ -21,6 +22,7 @@ def main():
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--steps", type=int, default=12)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch).reduced()
     model = zoo.get_model(cfg)

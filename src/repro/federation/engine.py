@@ -201,6 +201,11 @@ def screen_stats(base, trees: Sequence, weights: Sequence[float]):
             np.asarray(cos, np.float64))
 
 
+def _axis_pieces(arr: jax.Array, axis: int) -> int:
+    """Number of shards ``arr``'s placement splits ``axis`` into."""
+    return arr.shape[axis] // arr.sharding.shard_shape(arr.shape)[axis]
+
+
 def _pad_axis1(arr: np.ndarray, pad: int) -> np.ndarray:
     """Append ``pad`` zero rows along the client axis (axis 1)."""
     z = np.zeros((arr.shape[0], pad) + arr.shape[2:], arr.dtype)
@@ -427,6 +432,14 @@ class BatchedEngine:
                 tm.observe("engine.dispatch_s", dur, compiled=compiled)
                 tm.inc("engine.clients", n_real)
                 tm.inc("engine.phantom_rows", size - n_real)
+                # placement as dispatched: how many pieces the client
+                # axis of each stack is really split into (min/max over
+                # every dispatch)
+                tm.observe("engine.client_axis_shards",
+                           _axis_pieces(jax.tree_util.tree_leaves(
+                               lora_stack)[0], 0), stack="lora")
+                tm.observe("engine.client_axis_shards",
+                           _axis_pieces(toks, 1), stack="batch")
                 tm.set_gauge("engine.compile_cache", fn._cache_size(),
                              split=lbl, prox=prox_l)
             else:

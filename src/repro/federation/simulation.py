@@ -1,6 +1,7 @@
 """End-to-end ELSA federation simulation (Alg. 1) plus FL baselines.
 
-Runs the *real* machinery end to end on a reduced model: behavioral
+Runs the *real* machinery end to end on a reduced model (or, with
+``FedConfig(reduced=False)``, at the published config's widths): behavioral
 fingerprinting on a public probe set, trust scoring, latency-aware spectral
 clustering, per-client dynamic splits, split training through the
 SS-OP∘sketch channel, edge FedAvg, and coherence/trust-weighted cloud
@@ -79,8 +80,10 @@ class FedConfig:
     use_channel: bool = True
     use_ssop: bool = True
     model: str = "bert-base"             # split-model registry name
-    layers: Optional[int] = None         # reduced-model depth (tests: 4;
-                                         # None -> 8)
+    reduced: bool = True                 # False: the published config's
+                                         # widths instead of reduced()
+    layers: Optional[int] = None         # model depth; None -> 8 when
+                                         # reduced, else the config's own
     bert_layers: Optional[int] = None    # DEPRECATED: use ``layers=``
     seq_len: int = 24                    # synthetic-task sequence length
     class_sharpness: float = 4.0         # synthetic-task separability
@@ -141,7 +144,7 @@ class FedConfig:
                 DeprecationWarning, stacklevel=3)
             if self.layers is None:
                 self.layers = self.bert_layers
-        if self.layers is None:
+        if self.layers is None and self.reduced:
             self.layers = 8
         self.bert_layers = self.layers   # keep legacy readers consistent
 
@@ -172,7 +175,7 @@ class Federation:
         if fed.vocab_size:
             overrides["vocab_size"] = fed.vocab_size
         self.model = get_split_model(fed.model, num_layers=fed.layers,
-                                     dtype=fed.dtype,
+                                     dtype=fed.dtype, reduced=fed.reduced,
                                      pooling=(fed.pooling
                                               if fed.pooling != "cls"
                                               else None),
@@ -446,11 +449,14 @@ class Federation:
     # ------------------------------------------------------------------
     def evaluate(self, lora) -> float:
         if self._eval_fn is None:
-            # tokens stay an argument (not a closure) so XLA doesn't try
-            # to constant-fold the embedding of the whole test set
-            self._eval_fn = jax.jit(lambda lp, toks: self.model.forward(
-                self.frozen, lp, toks)[1])
-        logits = self._eval_fn(lora, jnp.asarray(self.test_tokens))
+            # params and tokens stay arguments (not closures): a closed-
+            # over array is baked into the program as a constant, which
+            # at published widths copies every frozen weight into the
+            # executable and makes XLA constant-fold the test set
+            self._eval_fn = jax.jit(lambda fr, lp, toks: self.model.forward(
+                fr, lp, toks)[1])
+        logits = self._eval_fn(self.frozen, lora,
+                               jnp.asarray(self.test_tokens))
         return self.model.accuracy(logits, self.test_tokens,
                                    self.test_labels)
 
@@ -458,11 +464,11 @@ class Federation:
     def _batched_probe_embeddings(self, loras):
         """Probe embeddings for a list of lora trees: (N, Q, D)."""
         if self._probe_fn is None:
-            self._probe_fn = jax.jit(jax.vmap(
-                lambda lp, toks: self.model.probe_repr(
-                    self.frozen, lp, toks),
-                in_axes=(0, None)))
-        return self._probe_fn(stack_trees(loras), jnp.asarray(self.probe))
+            # frozen params as an argument, as in evaluate()
+            self._probe_fn = jax.jit(jax.vmap(self.model.probe_repr,
+                                              in_axes=(None, 0, None)))
+        return self._probe_fn(self.frozen, stack_trees(loras),
+                              jnp.asarray(self.probe))
 
     def profile_clients(self):
         """Phase 1: warmup locally, fingerprint, trust, cluster.

@@ -35,3 +35,14 @@ def resolve_interpret(value: Optional[bool] = None) -> bool:
         return _INTERPRET_OVERRIDE
     import jax
     return jax.default_backend() != "tpu"
+
+
+def block_size(dim: int, cap: int = 512, align: int = 128) -> int:
+    """Tile extent for a kernel axis of length ``dim``: the largest
+    multiple of ``align`` that is <= ``cap`` and divides ``dim``, else
+    ``dim`` itself (a full-extent block is always legal for Mosaic).
+    BERT-base's 768 gets 384 where a fixed 512 would not divide it."""
+    for b in range(min(cap, dim) // align * align, 0, -align):
+        if dim % b == 0:
+            return b
+    return dim

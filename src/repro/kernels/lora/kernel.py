@@ -14,7 +14,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels import resolve_interpret
+from repro.kernels import block_size, resolve_interpret
 
 
 def _lora_kernel(x_ref, w_ref, a_ref, b_ref, o_ref, acc_ref, accp_ref, *,
@@ -45,14 +45,16 @@ def lora_matmul_td(x, w, a, b, scale: float, *, bt: int = 256,
                    interpret: bool | None = None):
     """x: (T, K); w: (K, O); a: (K, r); b: (r, O) -> (T, O).
 
+    ``bo``/``bk`` are caps: each tile is the largest multiple of 128
+    below the cap that divides its axis (:func:`repro.kernels.block_size`).
     ``interpret=None`` -> backend-aware default (compiled on TPU).
     """
     interpret = resolve_interpret(interpret)
     T, K = x.shape
     _, O = w.shape
     r = a.shape[1]
-    bt, bo, bk = min(bt, T), min(bo, O), min(bk, K)
-    assert T % bt == 0 and O % bo == 0 and K % bk == 0
+    bt, bo, bk = min(bt, T), block_size(O, bo), block_size(K, bk)
+    assert T % bt == 0, (T, bt)
     nt, no, nk = T // bt, O // bo, K // bk
     kernel = functools.partial(_lora_kernel, nk=nk, scale=scale)
     return pl.pallas_call(

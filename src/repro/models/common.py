@@ -24,8 +24,7 @@ NEG_INF = -1e30
 def stack_specs(n: int, tree):
     """Prepend a ('layers', n) dim to every Spec in the tree (for lax.scan)."""
     return jax.tree_util.tree_map(
-        lambda s: Spec((n,) + s.shape, ("layers",) + s.axes, s.init, s.scale,
-                       s.dtype),
+        lambda s: s._replace(shape=(n,) + s.shape, axes=("layers",) + s.axes),
         tree, is_leaf=lambda x: isinstance(x, Spec))
 
 
@@ -44,10 +43,13 @@ def attn_specs(cfg, *, cross: bool = False):
     """q/k/v/o projection specs (+ optional bias, + LoRA adapters)."""
     d, h, kv, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     p = {
-        "wq": Spec((d, h, hd), ("embed", "heads", None)),
-        "wk": Spec((d, kv, hd), ("embed", "kv_heads", None)),
-        "wv": Spec((d, kv, hd), ("embed", "kv_heads", None)),
-        "wo": Spec((h, hd, d), ("heads", None, "embed")),
+        "wq": Spec((d, h, hd), ("embed", "heads", None), fan_in_axes=(-3,)),
+        "wk": Spec((d, kv, hd), ("embed", "kv_heads", None),
+                   fan_in_axes=(-3,)),
+        "wv": Spec((d, kv, hd), ("embed", "kv_heads", None),
+                   fan_in_axes=(-3,)),
+        "wo": Spec((h, hd, d), ("heads", None, "embed"),
+                   fan_in_axes=(-3, -2)),
     }
     if cfg.qkv_bias:
         p["bq"] = Spec((h, hd), ("heads", None), "zeros")
@@ -65,7 +67,8 @@ def attn_lora_specs(cfg):
         if t not in dims:
             continue
         if t == "o":
-            out[f"{t}_a"] = Spec((h, hd, r), ("heads", None, "lora_r"))
+            out[f"{t}_a"] = Spec((h, hd, r), ("heads", None, "lora_r"),
+                                 fan_in_axes=(-3, -2))
             out[f"{t}_b"] = Spec((r, d), ("lora_r", "embed"), "zeros")
         else:
             n, e = dims[t]

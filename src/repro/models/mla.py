@@ -21,12 +21,16 @@ def mla_specs(cfg):
     p = {
         "w_dq": Spec((d, a.q_lora_rank), ("embed", "lora_r")),
         "q_norm": {"scale": Spec((a.q_lora_rank,), (None,), "ones")},
-        "w_uq": Spec((a.q_lora_rank, h, qk), ("lora_r", "heads", None)),
+        "w_uq": Spec((a.q_lora_rank, h, qk), ("lora_r", "heads", None),
+                     fan_in_axes=(-3,)),
         "w_dkv": Spec((d, a.kv_lora_rank + a.rope_head_dim), ("embed", None)),
         "kv_norm": {"scale": Spec((a.kv_lora_rank,), (None,), "ones")},
-        "w_uk": Spec((a.kv_lora_rank, h, a.nope_head_dim), (None, "heads", None)),
-        "w_uv": Spec((a.kv_lora_rank, h, a.v_head_dim), (None, "heads", None)),
-        "wo": Spec((h, a.v_head_dim, d), ("heads", None, "embed")),
+        "w_uk": Spec((a.kv_lora_rank, h, a.nope_head_dim), (None, "heads", None),
+                     fan_in_axes=(-3,)),
+        "w_uv": Spec((a.kv_lora_rank, h, a.v_head_dim), (None, "heads", None),
+                     fan_in_axes=(-3,)),
+        "wo": Spec((h, a.v_head_dim, d), ("heads", None, "embed"),
+                   fan_in_axes=(-3, -2)),
     }
     return p
 
@@ -41,7 +45,8 @@ def mla_lora_specs(cfg):
         out["q_a"] = Spec((d, r), ("embed", "lora_r"))
         out["q_b"] = Spec((r, h, qk), ("lora_r", "heads", None), "zeros")
     if "o" in cfg.lora.targets:
-        out["o_a"] = Spec((h, a.v_head_dim, r), ("heads", None, "lora_r"))
+        out["o_a"] = Spec((h, a.v_head_dim, r), ("heads", None, "lora_r"),
+                          fan_in_axes=(-3, -2))
         out["o_b"] = Spec((r, d), ("lora_r", "embed"), "zeros")
     return out
 

@@ -23,9 +23,16 @@ class Spec(NamedTuple):
     init: str = "normal"              # normal | zeros | ones | const | embed
     scale: float = 1.0                # stddev multiplier / const value
     dtype: Optional[str] = None       # per-leaf dtype override (e.g. 'int32')
+    # axes the projection contracts, as negative indices (so a stacked
+    # leading 'layers' dim keeps them valid); None: shape[-2] (2-D and
+    # expert-batched matrices), or shape[-1] for vectors
+    fan_in_axes: Optional[Tuple[int, ...]] = None
 
     def fan_in_scale(self) -> float:
-        fan_in = self.shape[-2] if len(self.shape) >= 2 else self.shape[-1]
+        if self.fan_in_axes is not None:
+            fan_in = math.prod(self.shape[a] for a in self.fan_in_axes)
+        else:
+            fan_in = self.shape[-2] if len(self.shape) >= 2 else self.shape[-1]
         return 1.0 / math.sqrt(max(fan_in, 1))
 
 

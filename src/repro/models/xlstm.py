@@ -65,7 +65,8 @@ def slstm_specs(cfg):
     hd = d // h
     gates = {}
     for g in ("i", "f", "z", "o"):
-        gates[f"w_{g}"] = Spec((d, h, hd), ("embed", "heads", None))
+        gates[f"w_{g}"] = Spec((d, h, hd), ("embed", "heads", None),
+                               fan_in_axes=(-3,))
         gates[f"r_{g}"] = Spec((h, hd, hd), ("heads", None, None), "normal", 0.5)
         gates[f"b_{g}"] = Spec((h, hd), ("heads", None),
                                "const" if g == "f" else "zeros",

@@ -6,12 +6,3 @@ import sys
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-
-
-def make_abstract_mesh(sizes, names):
-    """AbstractMesh across jax versions: (sizes, names) vs shape_tuple."""
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(sizes, names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))

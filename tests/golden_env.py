@@ -1,17 +1,14 @@
 """Environment fingerprint for golden-history records.
 
-Golden histories pin *bit-level* reproducibility, but CPU f32 bits are
+Golden histories pin *bit-level* reproducibility, and CPU f32 bits are
 only stable within one numerical environment: a jax/jaxlib upgrade
-changes XLA codegen (fusion, FMA contraction), the split-model gradient
-map is chaotic (parameter-Lipschitz ~1e5, docs/engine.md), and the
-recorded trajectories drift by ~1e-3 on a two-round horizon.  Each
-golden therefore carries the fingerprint of the environment it was
-recorded in: a matching environment asserts at float precision
-(atol 1e-9 ≈ bit-identical for f32), a drifted one falls back to a
-tolerance band that still catches wiring bugs (wrong method, broken
-aggregation, channel misrouting) without failing on codegen drift.
+changes XLA codegen and random streams, and the split-model gradient
+map is chaotic (parameter-Lipschitz ~1e5, docs/engine.md).  The repo
+supports one installation, the goldens are recorded in it and asserted
+at float precision; each golden carries the fingerprint of the
+environment that recorded it so a failure names both environments.
 
-Re-pin after an intentional container upgrade with::
+Re-pin after an intentional toolchain upgrade with::
 
     PYTHONPATH=src python tests/golden/regen_bert_parity.py
 """
@@ -31,9 +28,3 @@ def fingerprint() -> dict:
         "machine": platform.machine(),
         "backend": jax.default_backend(),
     }
-
-
-def matches(recorded) -> bool:
-    """True when the current environment is the one the golden was
-    recorded in (goldens predating the fingerprint never match)."""
-    return recorded == fingerprint() if recorded else False

@@ -38,7 +38,7 @@ def _max_tree_diff(a, b):
 @pytest.fixture(scope="module")
 def x64_feds():
     from repro.federation.simulation import FedConfig, Federation
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         fb = Federation(FedConfig(**PARITY_KW), backend="batched")
         fr = Federation(FedConfig(**PARITY_KW), backend="reference")
         yield fb, fr
@@ -64,13 +64,13 @@ def _assert_run_parity(fb, fr, method, rounds=2, steps=2):
 
 def test_engine_matches_reference_elsa(x64_feds):
     """Full Alg. 1 (clustered, SS-OP∘sketch channel on): batched == ref."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         _assert_run_parity(*x64_feds, "elsa")
 
 
 def test_engine_matches_reference_fedprox(x64_feds):
     """FedProx anchor term vectorizes identically (broadcast anchor)."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         _assert_run_parity(*x64_feds, "fedprox")
 
 
@@ -78,7 +78,7 @@ def test_engine_single_step_parity_at_training_lr(x64_feds):
     """One local step at the real lr: gradient math identical to 1e-8
     (before chaotic trajectory amplification can kick in)."""
     from repro.data.pipeline import infinite_batches
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         fb, fr = x64_feds
         lr0 = fb.fed.lr
         clients = list(range(fb.fed.n_clients))

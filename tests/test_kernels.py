@@ -52,7 +52,8 @@ def test_flash_attention_bshd_wrapper():
 
 
 @pytest.mark.parametrize("t,d,y,z", [(64, 256, 3, 32), (128, 512, 5, 64),
-                                     (32, 128, 4, 16)])
+                                     (32, 128, 4, 16),
+                                     (512, 768, 3, 121)])   # BERT-base
 def test_count_sketch_kernels(t, d, y, z):
     h = jax.random.normal(jax.random.PRNGKey(0), (t, d))
     plan = make_plan(d, y, z, seed=3)
@@ -89,7 +90,8 @@ def test_ssop_kernel(t, d, r):
     np.testing.assert_allclose(np.asarray(back), np.asarray(h), atol=1e-4)
 
 
-@pytest.mark.parametrize("t,k,o,r", [(64, 128, 256, 8), (128, 256, 128, 16)])
+@pytest.mark.parametrize("t,k,o,r", [(64, 128, 256, 8), (128, 256, 128, 16),
+                                     (256, 768, 768, 8)])   # BERT-base
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_lora_kernel(t, k, o, r, dtype):
     ks = jax.random.split(jax.random.PRNGKey(0), 4)

@@ -9,10 +9,9 @@ from repro.launch.mesh import (chips, client_axes, data_axes,
                                make_federation_mesh)
 from repro.launch.train import batch_pspec, elsa_boundaries, elsa_channel_specs
 
-from conftest import make_abstract_mesh
 
-MESH = make_abstract_mesh((16, 16), ("data", "model"))
-MESH3 = make_abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_batch_pspec_divisible():

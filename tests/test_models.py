@@ -102,6 +102,51 @@ def test_decode_matches_prefill(arch):
     np.testing.assert_allclose(dec, ref, atol=5e-4, rtol=5e-3)
 
 
+def _projection_specs():
+    """(id, spec, dims the model's einsum contracts over) for every 3-D
+    projection; the contracted dims are read off the einsums here,
+    independently of the spec's own fan-in."""
+    from repro.models import common as cm, mla, moe, xlstm
+    out = []
+    llama = get_config("llama3-8b").reduced()
+    d, h, kv, hd = (llama.d_model, llama.num_heads, llama.num_kv_heads,
+                    llama.resolved_head_dim)
+    attn, lora = cm.attn_specs(llama), cm.attn_lora_specs(llama)
+    out += [("attn.wq", attn["wq"], d), ("attn.wk", attn["wk"], d),
+            ("attn.wv", attn["wv"], d), ("attn.wo", attn["wo"], h * hd),
+            ("attn_lora.o_a", lora["o_a"], h * hd),
+            ("stacked attn.wo", cm.stack_specs(3, attn)["wo"], h * hd)]
+    ds = get_config("deepseek-v2-236b").reduced()
+    a, h = ds.mla, ds.num_heads
+    m, ml = mla.mla_specs(ds), mla.mla_lora_specs(ds)
+    out += [("mla.w_uq", m["w_uq"], a.q_lora_rank),
+            ("mla.w_uk", m["w_uk"], a.kv_lora_rank),
+            ("mla.w_uv", m["w_uv"], a.kv_lora_rank),
+            ("mla.wo", m["wo"], h * a.v_head_dim),
+            ("mla_lora.o_a", ml["o_a"], h * a.v_head_dim)]
+    e = moe.moe_specs(ds)
+    out += [("moe.w_gate", e["w_gate"], ds.d_model),
+            ("moe.w_down", e["w_down"], e["w_down"].shape[-2])]
+    xl =get_config("xlstm-1.3b").reduced()
+    s = xlstm.slstm_specs(xl)
+    hd = s["w_i"].shape[-1]
+    out += [("slstm.w_i", s["w_i"], xl.d_model),
+            ("slstm.r_i", s["r_i"], hd)]
+    return out
+
+
+@pytest.mark.parametrize("name,spec,fan_in", _projection_specs(),
+                         ids=lambda v: v if isinstance(v, str) else "")
+def test_projection_init_std_is_inverse_sqrt_contracted_dim(name, spec,
+                                                            fan_in):
+    """A normal-init projection has std scale/sqrt(contracted dim), so
+    activations keep their scale through it at any width."""
+    w = np.asarray(init_tree({"w": spec}, jax.random.PRNGKey(0))["w"])
+    assert w.shape == spec.shape
+    np.testing.assert_allclose(w.std(), spec.scale / np.sqrt(fan_in),
+                               rtol=0.1, err_msg=name)
+
+
 def test_param_counts_full_configs():
     """Full (non-reduced) configs should land near their nameplate sizes."""
     expect = {"llama3-8b": (7.0e9, 9.0e9),

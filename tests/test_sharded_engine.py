@@ -174,17 +174,13 @@ def test_sharded_federation_matches_prerefactor_golden():
     np.testing.assert_array_equal(h["delta"], hu["delta"])
     assert _max_tree_diff(fed.last_theta, fu.last_theta) == 0.0
     if N_DEV == 1:
-        # float-precision only in the golden's recording environment;
-        # a drifted container falls back to the same tolerance band as
-        # tests/test_split_api.py (see tests/golden_env.py)
-        strict = golden_env.matches(gold.get("env"))
-        rtol, atol = (0, 1e-9) if strict else (0.05, 0.1)
-        np.testing.assert_allclose(h["loss"], gold["loss"], rtol=rtol,
-                                   atol=atol)
-        np.testing.assert_allclose(h["accuracy"], gold["accuracy"],
-                                   rtol=rtol, atol=atol)
-        np.testing.assert_allclose(h["delta"], gold["delta"], rtol=rtol,
-                                   atol=atol)
+        # float precision in the one supported installation
+        msg = (f"golden recorded under {gold.get('env')}, running under "
+               f"{golden_env.fingerprint()}")
+        tol = dict(rtol=0, atol=1e-9, err_msg=msg)
+        np.testing.assert_allclose(h["loss"], gold["loss"], **tol)
+        np.testing.assert_allclose(h["accuracy"], gold["accuracy"], **tol)
+        np.testing.assert_allclose(h["delta"], gold["delta"], **tol)
     assert h["round"] == gold["round"]
 
 
@@ -216,7 +212,7 @@ def test_fused_dispatch_bitwise_inert_multi_round(method):
 
 def _assert_sharded_parity(kw, method="elsa", rounds=2, steps=2):
     mesh = make_federation_mesh()        # all available devices
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         fu = Federation(FedConfig(**kw), backend="batched")
         hu = fu.run(method, global_rounds=rounds, steps_per_round=steps)
         fs = Federation(FedConfig(**kw), backend="batched", mesh=mesh)

@@ -8,10 +8,9 @@ from repro.models import zoo
 from repro.models.params import (DEFAULT_RULES, Spec, partition_spec,
                                  tree_pspecs)
 
-from conftest import make_abstract_mesh
 
-MESH = make_abstract_mesh((16, 16), ("data", "model"))
-MESH3 = make_abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_divisible_dims_shard():

@@ -162,28 +162,22 @@ def _assert_matches_golden(path):
     h = fed.run(gold["run"]["method"],
                 global_rounds=gold["run"]["global_rounds"],
                 steps_per_round=gold["run"]["steps_per_round"])
-    # in the golden's recording environment (tests/golden_env.py) the
-    # history must match at float precision; in a drifted container XLA
-    # codegen changes shift f32 bits and the chaotic gradient map
-    # amplifies them to ~1e-3 over this horizon, so fall back to a band
-    # that still catches wiring bugs (re-pin: tests/golden/
-    # regen_bert_parity.py)
-    strict = golden_env.matches(gold.get("env"))
-    rtol, atol = (0, 1e-9) if strict else (0.05, 0.1)
-    np.testing.assert_allclose(h["loss"], gold["loss"], rtol=rtol,
-                               atol=atol)
-    np.testing.assert_allclose(h["accuracy"], gold["accuracy"], rtol=rtol,
-                               atol=atol)
-    np.testing.assert_allclose(h["delta"], gold["delta"], rtol=rtol,
-                               atol=atol)
+    # float precision (atol 1e-9 ~ bit-identical for f32) in the one
+    # supported installation; re-pin after a toolchain upgrade with
+    # tests/golden/regen_bert_parity.py
+    msg = (f"golden recorded under {gold.get('env')}, running under "
+           f"{golden_env.fingerprint()}")
+    tol = dict(rtol=0, atol=1e-9, err_msg=msg)
+    np.testing.assert_allclose(h["loss"], gold["loss"], **tol)
+    np.testing.assert_allclose(h["accuracy"], gold["accuracy"], **tol)
+    np.testing.assert_allclose(h["delta"], gold["delta"], **tol)
     assert h["round"] == gold["round"]
     for n, ref in gold["client_losses"].items():
-        np.testing.assert_allclose(h["client_losses"][int(n)], ref,
-                                   rtol=rtol, atol=atol)
+        np.testing.assert_allclose(h["client_losses"][int(n)], ref, **tol)
     sums = [float(np.asarray(l, np.float64).sum())
             for l in jax.tree_util.tree_leaves(fed.last_theta)]
-    np.testing.assert_allclose(sums, gold["theta_leaf_sums"], rtol=rtol,
-                               atol=1e-7 if strict else atol)
+    np.testing.assert_allclose(sums, gold["theta_leaf_sums"], rtol=0,
+                               atol=1e-7, err_msg=msg)
 
 
 def test_bert_federation_matches_prerefactor_golden():

@@ -95,10 +95,7 @@ def test_hlo_parser_matches_xla_on_unrolled():
     c = jax.jit(f).lower(jnp.zeros((64, 128)),
                          jnp.zeros((4, 128, 128))).compile()
     parsed = analyze(c.as_text())
-    ca = c.cost_analysis()
-    if isinstance(ca, (list, tuple)):    # older jax returns [dict]
-        ca = ca[0]
-    xla = ca["flops"]
+    xla = c.cost_analysis()["flops"]
     assert abs(parsed.flops - xla) / xla < 0.05
 
 
