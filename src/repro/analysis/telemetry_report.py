@@ -2,7 +2,9 @@
 (docs/observability.md).
 
 Renders the merged run summary written by :func:`repro.telemetry.
-export_jsonl` — per-phase wall/simulated time, the simulated comm
+export_jsonl` — per-phase wall time (total, and self time: less what
+the phase's child spans cover, from the spans' parent ids) and
+simulated time, the simulated comm
 breakdown (seconds + wire bytes), runtime event counts, engine compile
 accounting, screening verdicts, and histogram digests — as one plain
 table, either from a finished file's summary line or rebuilt from the
@@ -49,6 +51,20 @@ def _series(counters: Dict[str, float], name: str) -> Dict[str, float]:
             if k == name or k.startswith(prefix)}
 
 
+def child_seconds(rounds: List[Dict[str, Any]]) -> Dict[str, float]:
+    """Per span name, the wall seconds its direct child spans cover
+    (spans without parent ids, as schema-1 files hold them, have no
+    children)."""
+    spans = [s for rec in rounds for s in rec.get("spans", ())]
+    name_of = {s["id"]: s["name"] for s in spans if "id" in s}
+    out: Dict[str, float] = {}
+    for s in spans:
+        parent = name_of.get(s.get("parent"))
+        if parent is not None:
+            out[parent] = out.get(parent, 0.0) + s.get("dur_s", 0.0)
+    return out
+
+
 def render(data: Dict[str, Any], show_rounds: bool = False) -> str:
     """Format one parsed telemetry file (:func:`read_jsonl` output)."""
     s = data["summary"]
@@ -62,13 +78,16 @@ def render(data: Dict[str, Any], show_rounds: bool = False) -> str:
     spans: Dict[str, Dict[str, float]] = s.get("spans", {})
     if spans:
         lines.append("")
-        lines.append("phase            count       wall         sim")
+        lines.append("phase              count       wall       self"
+                     "         sim")
         ordered = [p for p in PHASES if p in spans] \
             + sorted(k for k in spans if k not in PHASES)
+        children = child_seconds(data["rounds"])
         for name in ordered:
             agg = spans[name]
-            lines.append(f"{name:<14} {int(agg['count']):7d} "
+            lines.append(f"{name:<16} {int(agg['count']):7d} "
                          f"{_fmt_s(agg['wall_s'])} "
+                         f"{_fmt_s(agg['wall_s'] - children.get(name, 0.0))} "
                          f"{_fmt_s(agg['sim_s'])}")
 
     sim_rows = [(lbl, counters.get(key, 0.0)) for key, lbl in SIM_COUNTERS

@@ -378,53 +378,61 @@ class BatchedEngine:
 
         pending = []
         for split, members in buckets.items():
-            toks, labs, wts = stack_padded_batches(
-                [batches[n] for n in members], self.batch_size)
-            n_real = len(members)
-            size = (bucket_size(n_real, self.n_shards) if self.pad_cohorts
-                    else -(-n_real // self.n_shards) * self.n_shards)
-            if size > n_real:
-                pad = size - n_real
-                toks = _pad_axis1(toks, pad)
-                labs = _pad_axis1(labs, pad)
-                wts = _pad_axis1(wts, pad)   # zero weights: inert rows
-            if per_client:
-                # per-client starting points; phantom rows repeat the
-                # last member (zero weights keep them inert)
-                trees = [theta[n] for n in members]
-                trees += [theta[members[-1]]] * (size - n_real)
-                lora_stack = stack_trees(trees)
-            else:
-                lora_stack = broadcast_tree(theta, size)
-            ssop_stack = None
-            if self.use_channel and self.use_ssop:
-                ssops = [channels[n].ssop for n in members]
-                ssops += [ssops[-1]] * (size - n_real)   # phantom rows
-                ssop_stack = stack_ssops(ssops)
-            if self.mesh is not None:
-                lora_stack = jax.device_put(lora_stack, self._shard_clients)
-                if ssop_stack is not None:
-                    ssop_stack = jax.device_put(ssop_stack,
+            # padding and stacking the batches, LoRA and SS-OP trees, and
+            # their copies to the device
+            with tm.span("engine.stack", n_clients=len(members)):
+                toks, labs, wts = stack_padded_batches(
+                    [batches[n] for n in members], self.batch_size)
+                n_real = len(members)
+                size = (bucket_size(n_real, self.n_shards)
+                        if self.pad_cohorts
+                        else -(-n_real // self.n_shards) * self.n_shards)
+                if size > n_real:
+                    pad = size - n_real
+                    toks = _pad_axis1(toks, pad)
+                    labs = _pad_axis1(labs, pad)
+                    wts = _pad_axis1(wts, pad)   # zero weights: inert rows
+                if per_client:
+                    # per-client starting points; phantom rows repeat the
+                    # last member (zero weights keep them inert)
+                    trees = [theta[n] for n in members]
+                    trees += [theta[members[-1]]] * (size - n_real)
+                    lora_stack = stack_trees(trees)
+                else:
+                    lora_stack = broadcast_tree(theta, size)
+                ssop_stack = None
+                if self.use_channel and self.use_ssop:
+                    ssops = [channels[n].ssop for n in members]
+                    ssops += [ssops[-1]] * (size - n_real)   # phantom rows
+                    ssop_stack = stack_ssops(ssops)
+                if self.mesh is not None:
+                    lora_stack = jax.device_put(lora_stack,
                                                 self._shard_clients)
-                toks, labs, wts = jax.device_put(
-                    (toks, labs, wts), self._shard_batches)
-            else:
-                toks, labs, wts = (jnp.asarray(toks), jnp.asarray(labs),
-                                   jnp.asarray(wts))
+                    if ssop_stack is not None:
+                        ssop_stack = jax.device_put(ssop_stack,
+                                                    self._shard_clients)
+                    toks, labs, wts = jax.device_put(
+                        (toks, labs, wts), self._shard_batches)
+                else:
+                    toks, labs, wts = (jnp.asarray(toks), jnp.asarray(labs),
+                                       jnp.asarray(wts))
             fn = self._round_fn(split, prox_anchor is not None)
-            if tm.enabled():
-                # compile-vs-execute accounting: the jit cache growing
-                # across this dispatch means a fresh trace+compile for
-                # this (split, cohort-bucket) shape; steady state stays
-                # at one executable per (split, bucket)
+            tel = tm.get()
+            before = fn._cache_size() if tel is not None else 0
+            # the call only enqueues the round on an asynchronous
+            # backend: the host waits for it in engine.fetch
+            with tm.span("engine.dispatch"):
+                t0 = time.perf_counter()
+                out_stack, losses = fn(self.frozen, lora_stack, ssop_stack,
+                                       prox_anchor, toks, labs, wts)
+                dur = time.perf_counter() - t0
+            if tel is not None:
+                # the jit cache growing across this dispatch means a
+                # fresh trace+compile for this (split, cohort-bucket)
+                # shape; steady state stays at one executable per
+                # (split, bucket)
                 lbl = f"p{split.p}q{split.q}o{split.o}"
                 prox_l = prox_anchor is not None
-                before = fn._cache_size()
-                t0 = time.perf_counter()
-                out_stack, losses = fn(self.frozen, lora_stack,
-                                       ssop_stack, prox_anchor,
-                                       toks, labs, wts)
-                dur = time.perf_counter() - t0
                 compiled = fn._cache_size() > before
                 if compiled:
                     tm.inc("engine.jit_compiles", 1, split=lbl,
@@ -442,17 +450,17 @@ class BatchedEngine:
                            _axis_pieces(toks, 1), stack="batch")
                 tm.set_gauge("engine.compile_cache", fn._cache_size(),
                              split=lbl, prox=prox_l)
-            else:
-                out_stack, losses = fn(self.frozen, lora_stack,
-                                       ssop_stack, prox_anchor,
-                                       toks, labs, wts)
             pending.append((members, out_stack, losses))
 
         # one host sync for every bucket's (steps, N) loss array
-        loss_host = jax.device_get([l for (_, _, l) in pending])
+        with tm.span("engine.fetch"):
+            loss_host = jax.device_get([l for (_, _, l) in pending])
+        tm.inc("host.syncs", 1, site="engine.fetch")
         results: Dict[int, Tuple[object, float]] = {}
-        for (members, out_stack, _), ls in zip(pending, loss_host):
-            per_client = ls.mean(axis=0)                     # (N,)
-            for i, n in enumerate(members):
-                results[n] = (index_tree(out_stack, i), float(per_client[i]))
+        with tm.span("engine.unstack"):
+            for (members, out_stack, _), ls in zip(pending, loss_host):
+                per_client = ls.mean(axis=0)                     # (N,)
+                for i, n in enumerate(members):
+                    results[n] = (index_tree(out_stack, i),
+                                  float(per_client[i]))
         return results

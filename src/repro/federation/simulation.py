@@ -440,8 +440,9 @@ class Federation:
         channels = {n: self.channel_for(n, theta[n] if per_client
                                         else theta, emb=emb)
                     for n in clients}
-        batches = {n: [next(iters[n]) for _ in range(n_steps)]
-                   for n in clients}
+        with tm.span("data.draw"):
+            batches = {n: [next(iters[n]) for _ in range(n_steps)]
+                       for n in clients}
         return self.engine.run_clients(theta, clients, splits, channels,
                                        batches, prox_anchor=prox_anchor,
                                        per_client_theta=per_client)
@@ -457,6 +458,7 @@ class Federation:
                 fr, lp, toks)[1])
         logits = self._eval_fn(self.frozen, lora,
                                jnp.asarray(self.test_tokens))
+        tm.inc("host.syncs", 1, site="eval")
         return self.model.accuracy(logits, self.test_tokens,
                                    self.test_labels)
 
@@ -485,15 +487,19 @@ class Federation:
         clients = list(range(fed.n_clients))
         fps, norms, warm_loras = [], [], {}
         if self.backend == "batched":
-            res = self.group_steps(clients, self.lora0,
-                                   fed.local_warmup_steps, iters,
-                                   use_split=False)
+            with tm.span("profile.warmup"):
+                res = self.group_steps(clients, self.lora0,
+                                       fed.local_warmup_steps, iters,
+                                       use_split=False)
             warm_loras = {n: res[n][0] for n in clients}
-            embs = self._batched_probe_embeddings(
-                [warm_loras[n] for n in clients])
-            for n in clients:
-                fps.append(fingerprint(embs[n]))
-                norms.append(np.asarray(jnp.linalg.norm(embs[n], axis=-1)))
+            with tm.span("profile.probe"):
+                embs = self._batched_probe_embeddings(
+                    [warm_loras[n] for n in clients])
+                for n in clients:
+                    fps.append(fingerprint(embs[n]))
+                    norms.append(np.asarray(jnp.linalg.norm(embs[n],
+                                                            axis=-1)))
+                tm.inc("host.syncs", len(clients), site="profile.probe")
         else:
             for n in clients:
                 lora_n, _ = self.client_steps(n, self.lora0,
@@ -503,11 +509,18 @@ class Federation:
                 emb = self._probe_embeddings(lora_n)
                 fps.append(fingerprint(emb))
                 norms.append(np.asarray(jnp.linalg.norm(emb, axis=-1)))
-        div = divergence_matrix(fps)
-        trust = trust_scores(div, np.stack(norms))
-        result = clus.cluster_clients(div, trust, self.topo.latency,
-                                      tau_max=fed.tau_max, gamma=fed.gamma,
-                                      w_min=fed.w_min, seed=fed.seed)
+                tm.inc("host.syncs", 1, site="profile.probe")
+        with tm.span("profile.kl"):
+            div = divergence_matrix(fps)
+            # one host read of each pair's divergence
+            tm.inc("host.syncs", len(fps) * (len(fps) - 1) // 2,
+                   site="profile.kl")
+        with tm.span("profile.cluster"):
+            trust = trust_scores(div, np.stack(norms))
+            result = clus.cluster_clients(div, trust, self.topo.latency,
+                                          tau_max=fed.tau_max,
+                                          gamma=fed.gamma, w_min=fed.w_min,
+                                          seed=fed.seed)
         return div, trust, result, warm_loras
 
     # ------------------------------------------------------------------
@@ -802,7 +815,12 @@ class Federation:
                                                     theta, theta_new)
                     theta_new, server_state = server_opt.update(
                         theta, pseudo, server_state)
-                delta = agg.global_delta(theta_new, theta)
+                with tm.span("agg.delta"):
+                    delta = agg.global_delta(theta_new, theta)
+                if tm.enabled():
+                    # one host read per adapter leaf
+                    tm.inc("host.syncs", len(jax.tree_util.tree_leaves(
+                        theta_new)), site="agg.delta")
             theta = theta_new
             if g % eval_every == 0 or g == global_rounds - 1:
                 with tm.span("eval", round=g):

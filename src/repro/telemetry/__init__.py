@@ -28,8 +28,14 @@ Instrumented layers (all no-ops while disabled):
   the determinism trace), schedulers record round-lifecycle spans
   (``dispatch``/``local_steps``/``uplink``/``edge_agg``/``cloud_agg``/
   ``eval``) and per-phase simulated seconds + comm bytes;
-- ``repro.federation.engine`` — jit compiles per (split, bucket),
-  compile-vs-cached dispatch wall time, cohort/phantom sizes,
+- ``repro.federation.simulation`` — the round loop's lifecycle spans,
+  leaf spans of the host work inside profiling (``profile.warmup``/
+  ``probe``/``kl``/``cluster``), the batch draws (``data.draw``) and the
+  global delta (``agg.delta``), and ``host.syncs{site=...}``, the host's
+  reads of device values;
+- ``repro.federation.engine`` — spans of each local round's stacking,
+  dispatch, loss fetch and unstacking, jit compiles per (split,
+  bucket), compile-vs-cached dispatch wall time, cohort/phantom sizes,
   donated-buffer placement;
 - ``repro.core.screening`` — verdict counters by reason + trust-ledger
   gauge snapshots;

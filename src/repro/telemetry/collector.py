@@ -13,9 +13,16 @@ One :class:`Telemetry` instance collects everything a run emits
   request latency, checkpoint save/restore latency;
 - **spans** — wall-clock timed sections of the round lifecycle
   (``dispatch -> local_steps -> uplink -> edge_agg -> cloud_agg ->
-  eval``), recorded via the ``with telemetry.span(name, ...)`` context
-  manager or, for phases that only exist on the simulated clock,
-  ``record_span(name, dur_s=0, sim_s=...)``.
+  eval``) and of the host work inside them, recorded via the ``with
+  telemetry.span(name, ...)`` context manager or, for phases that only
+  exist on the simulated clock, ``record_span(name, dur_s=0,
+  sim_s=...)``.  Spans nest: each record carries its own ``id``, the
+  ``parent`` id of the span open around it and its start ``t0_s``
+  (seconds since the collector started), so a span's self time is its
+  ``dur_s`` less what its children cover.  While a span is open it also
+  holds a ``jax.profiler.TraceAnnotation("elsa.<name>")``, so a profile
+  recorded around the run shows the same spans on the device trace's
+  clock.
 
 ``end_round(g)`` closes one round: pending spans plus the counter
 *deltas* since the previous round boundary become one per-round record,
@@ -26,7 +33,8 @@ runs and mergeable across processes.
 The module is intentionally free of any ``repro`` import (instrumented
 layers import *it*, never the reverse) and never touches device arrays:
 recording is pure host-side bookkeeping, so an enabled run computes
-bit-identical histories to a disabled one.
+bit-identical histories to a disabled one.  JAX is imported only when
+an enabled collector opens its first span.
 """
 from __future__ import annotations
 
@@ -34,8 +42,13 @@ import bisect
 import time
 from typing import Any, Dict, List, Optional, Sequence
 
-#: JSONL schema version written by the exporter.
-SCHEMA_VERSION = 1
+#: JSONL schema version written by the exporter.  Version 2 added the
+#: span keys ``id``, ``parent`` and ``t0_s``; version-1 files read the
+#: same, their spans without them.
+SCHEMA_VERSION = 2
+
+#: Prefix of the profiler annotation each open span holds.
+TRACE_PREFIX = "elsa."
 
 #: Default histogram bucket upper bounds (seconds, log-spaced).  Values
 #: above the last bound land in the +inf overflow bucket.
@@ -84,9 +97,12 @@ class Histogram:
 
 
 class _SpanCtx:
-    """Context manager recording one wall-timed span on exit."""
+    """Context manager recording one wall-timed span on exit.
 
-    __slots__ = ("_tel", "name", "attrs", "_t0")
+    The profiler annotation opens before the clock starts and closes
+    after it stops, so the trace's interval holds the recorded one."""
+
+    __slots__ = ("_tel", "name", "attrs", "_t0", "_id", "_parent", "_ann")
 
     def __init__(self, tel: "Telemetry", name: str, attrs: Dict[str, Any]):
         self._tel = tel
@@ -98,13 +114,20 @@ class _SpanCtx:
         self.attrs.update(attrs)
 
     def __enter__(self) -> "_SpanCtx":
+        from jax.profiler import TraceAnnotation
+        self._id, self._parent = self._tel._new_span()
+        self._tel._open_ids.append(self._id)
+        self._ann = TraceAnnotation(TRACE_PREFIX + self.name)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
-        self._tel.record_span(self.name,
-                              dur_s=time.perf_counter() - self._t0,
-                              **self.attrs)
+        t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        self._tel._open_ids.remove(self._id)
+        self._tel._record(self.name, self._id, self._parent,
+                          self._t0, t1 - self._t0, self.attrs)
         return False
 
 
@@ -151,6 +174,8 @@ class Telemetry:
         self.histograms: Dict[str, Histogram] = {}
         self.rounds: List[Dict[str, Any]] = []
         self._spans: List[Dict[str, Any]] = []   # pending (open round)
+        self._open_ids: List[int] = []           # ids of the open spans
+        self._next_id = 0
         self._round_base: Dict[str, float] = {}  # counters at last boundary
         if sink is not None:
             sink.emit_meta({"type": "meta", "schema": SCHEMA_VERSION,
@@ -180,8 +205,23 @@ class Telemetry:
     def record_span(self, name: str, dur_s: float = 0.0,
                     **attrs: Any) -> None:
         """Record a pre-measured span (simulated-clock phases pass their
-        duration via ``sim_s=`` attrs and keep ``dur_s`` at ~0)."""
-        rec: Dict[str, Any] = {"name": name, "dur_s": float(dur_s)}
+        duration via ``sim_s=`` attrs and keep ``dur_s`` at ~0), as a
+        child of the span open around the call."""
+        sid, parent = self._new_span()
+        self._record(name, sid, parent, time.perf_counter(), dur_s, attrs)
+
+    def _new_span(self):
+        """A new span id, and the id of the span open around it (None
+        at the top level)."""
+        sid = self._next_id
+        self._next_id += 1
+        return sid, (self._open_ids[-1] if self._open_ids else None)
+
+    def _record(self, name: str, sid: int, parent: Optional[int],
+                t0: float, dur_s: float, attrs: Dict[str, Any]) -> None:
+        rec: Dict[str, Any] = {"name": name, "id": sid, "parent": parent,
+                               "t0_s": t0 - self.started,
+                               "dur_s": float(dur_s)}
         if attrs:
             rec["attrs"] = attrs
         self._spans.append(rec)

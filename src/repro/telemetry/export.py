@@ -3,10 +3,12 @@ collector.Telemetry` (schema in docs/observability.md).
 
 A telemetry file is line-delimited JSON:
 
-- line 1: ``{"type": "meta", "schema": 1, "meta": {...}}``;
+- line 1: ``{"type": "meta", "schema": 2, "meta": {...}}``;
 - one ``{"type": "round", "round": g, "counters": {delta}, "gauges":
   {...}, "spans": [...], "sim_time_s": t}`` per closed round (counters
-  are per-round *deltas*; gauges are the values at the boundary);
+  are per-round *deltas*; gauges are the values at the boundary; each
+  span is ``{"name", "id", "parent", "t0_s", "dur_s", "attrs"}``, and a
+  schema-1 file's spans lack ``id``, ``parent`` and ``t0_s``);
 - last line: ``{"type": "summary", ...}`` — the cumulative counters,
   final gauges, full histogram states, and per-span-name wall/sim
   aggregates of the whole run (:func:`summarize`).

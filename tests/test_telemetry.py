@@ -12,7 +12,9 @@ The two load-bearing guarantees (docs/observability.md):
 """
 import json
 import os
+import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -96,6 +98,64 @@ def test_disabled_module_helpers_are_noops():
     assert isinstance(sp, tm.NullSpan)
     with sp as s:
         s.set(anything=1)           # still a no-op
+
+
+def test_span_records_nest_with_ids_parents_and_starts():
+    """Nested and sibling spans, a body that raises and a simulated-
+    clock record: ids are unique, each parent is the span open around
+    it, starts and ends nest, and self time is duration less children."""
+    from repro.analysis.telemetry_report import child_seconds
+    tel = tm.Telemetry()
+    with tel.span("a"):
+        with tel.span("b"):
+            time.sleep(0.002)
+        with pytest.raises(ValueError):
+            with tel.span("c"):
+                raise ValueError("body fails")
+        tel.record_span("sim", sim_s=1.0)
+    with tel.span("d"):
+        pass
+    recs = {s["name"]: s for s in tel._spans}
+    a, b, c, d, sim = (recs[k] for k in "a b c d sim".split())
+    assert len({s["id"] for s in tel._spans}) == 5
+    assert a["parent"] is None and d["parent"] is None
+    assert b["parent"] == c["parent"] == sim["parent"] == a["id"]
+    assert sim["dur_s"] == 0.0 and sim["attrs"] == {"sim_s": 1.0}
+    end = lambda s: s["t0_s"] + s["dur_s"]
+    assert a["t0_s"] <= b["t0_s"] and end(b) <= c["t0_s"]
+    assert end(c) <= sim["t0_s"] <= end(a) <= d["t0_s"]
+    assert tel._open_ids == []          # the raising span closed too
+    kids = child_seconds([{"spans": tel._spans}])
+    assert kids == {"a": b["dur_s"] + c["dur_s"]}
+    assert 0.0 <= a["dur_s"] - kids["a"] < a["dur_s"]
+
+
+def test_spans_hold_a_trace_annotation_only_while_enabled(monkeypatch):
+    opened = []
+
+    class Recorder:
+        def __init__(self, name):
+            opened.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    def refuse(name):
+        raise AssertionError(f"profiler called while disabled: {name}")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    assert tm.span("x") is tm.NULL_SPAN
+    with tm.span("x"):
+        tm.inc("host.syncs", 1, site="x")
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    with tm.session():
+        with tm.span("outer"):
+            with tm.span("inner"):
+                pass
+    assert opened == ["elsa.outer", "elsa.inner"]
 
 
 def test_session_nests_and_restores():
@@ -236,6 +296,49 @@ def test_screening_metrics_follow_verdicts(sync_runs):
     assert verdicts, "screened run must record verdict counters"
     assert tel.counter("screening.verdicts", verdict="nonfinite") > 0
     assert 0.0 < tel.gauge("screening.trust_mean") <= 1.0
+
+
+# ---------------------------------------------------------------------------
+# the plain round loop's nested spans and host-sync counts
+# ---------------------------------------------------------------------------
+
+LEAVES = {"profile.warmup": "profile", "profile.probe": "profile",
+          "profile.kl": "profile", "profile.cluster": "profile",
+          "agg.delta": "cloud_agg"}
+ENGINE = ("data.draw", "engine.stack", "engine.dispatch", "engine.fetch",
+          "engine.unstack")
+
+
+def test_round_loop_leaf_spans_and_syncs():
+    """A batched elsa run records every leaf span under its parent, one
+    ``engine.fetch`` sync per engine call (profiling's warm-up
+    included), and the same history as with telemetry off."""
+    fed = Federation(FedConfig(**SMALL_KW), backend="batched")
+    kw = dict(global_rounds=1, steps_per_round=1)
+    h_off = fed.run("elsa", **kw)
+    with tm.session() as tel:
+        h_on = fed.run("elsa", **kw)
+    for k in ("accuracy", "loss", "delta"):
+        assert h_on[k] == h_off[k], k
+    recs = [s for r in tel.rounds for s in r["spans"]] + tel._spans
+    by_id = {s["id"]: s for s in recs}
+    parent = lambda s: by_id[s["parent"]]["name"]
+    for name, want in LEAVES.items():
+        got = [parent(s) for s in recs if s["name"] == name]
+        assert got and set(got) == {want}, name
+    # the engine's leaves sit under local_steps or profiling's warm-up
+    for name in ENGINE:
+        got = {parent(s) for s in recs if s["name"] == name}
+        assert got == {"local_steps", "profile.warmup"}, name
+    fetches = [s for s in recs if s["name"] == "engine.fetch"]
+    assert any(parent(s) == "profile.warmup" for s in fetches)
+    assert tel.counter("host.syncs", site="engine.fetch") == len(fetches)
+    n = SMALL_KW["n_clients"]
+    assert tel.counter("host.syncs", site="profile.probe") == n
+    assert tel.counter("host.syncs", site="profile.kl") == n * (n - 1) // 2
+    assert tel.counter("host.syncs", site="eval") == len(h_on["round"])
+    assert tel.counter("host.syncs", site="agg.delta") == len(
+        jax.tree_util.tree_leaves(fed.lora0))
 
 
 # ---------------------------------------------------------------------------
