@@ -2,6 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import aggregation as agg
 from repro.core import clustering as clus
@@ -29,6 +30,33 @@ def test_divergence_matrix_shape_and_symmetry():
     assert d.shape == (4, 4)
     np.testing.assert_allclose(d, d.T, atol=1e-6)
     assert (np.diag(d) == 0).all()
+
+
+@pytest.mark.parametrize("n,q,chunk_bytes", [
+    (1, 8, None), (2, 8, None), (6, 8, None),
+    (1, 32, None), (2, 32, None), (6, 32, None),
+    # 4 pairs per chunk of 30: seven scanned chunks and a remainder of 2
+    (6, 32, 4 * (4 * 64 * 64 * 4)),
+])
+def test_divergence_matrix_matches_pairwise_sym_kl(monkeypatch, n, q,
+                                                   chunk_bytes):
+    """The one-program matrix equals a loop of ``sym_kl`` over pairs."""
+    import repro.core.fingerprint as fp_mod
+    d = 64
+    if chunk_bytes is not None:
+        monkeypatch.setattr(fp_mod, "_CHUNK_BYTES", chunk_bytes)
+        assert fp_mod._pair_chunk(d, 4) == 4 < n * (n - 1)
+    fps = [fingerprint(0.3 * i + (1.0 + 0.2 * i) * jax.random.normal(
+        jax.random.PRNGKey(i), (q, d))) for i in range(n)]
+    got = divergence_matrix(fps)
+    want = np.zeros((n, n))
+    for i in range(n):
+        for j in range(i + 1, n):
+            want[i, j] = want[j, i] = float(sym_kl(fps[i], fps[j]))
+    assert got.dtype == np.float64 and got.shape == (n, n)
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    np.testing.assert_array_equal(got, got.T)
+    assert (np.diag(got) == 0).all()
 
 
 def test_trust_downweights_outlier():
