@@ -39,11 +39,17 @@ NUMBERS = ("loss_gap", "update_gap", "edge_agg_gap", "eval_gap",
 
 
 def leaf_arrays(tree, prefix=""):
-    """{name: array}; stacked ``blocks`` leaves split by layer."""
+    """{name: array}; stacked ``blocks`` leaves split by layer, and the
+    entries of a list (layers kept one by one, as a ``prefix`` of
+    leading layers) named by index: ``/prefix[0]/attn/q_a``."""
     out = {}
     if isinstance(tree, dict):
         for k in sorted(tree):
             out.update(leaf_arrays(tree[k], f"{prefix}/{k}"))
+        return out
+    if isinstance(tree, (list, tuple)):
+        for i, t in enumerate(tree):
+            out.update(leaf_arrays(t, f"{prefix}[{i}]"))
         return out
     a = np.asarray(tree, np.float64)
     if prefix.startswith("/blocks/"):

@@ -1,5 +1,13 @@
 """Operations and bytes of ELSA's device work, from shapes alone.
 
+What every architecture shares is here: the channel, the sum over a
+stack of layers of any kind, and the least time of a dispatch. What
+belongs to one architecture is in ``counts/<family>.py``, found by the
+configuration file's ``family`` key. Such a module gives
+``block_forward(cfg, s, i)`` and ``block_backward(cfg, s, i, lowest)``
+for layer ``i``, ``head_forward(cfg, fed, s)``, ``head_backward(cfg,
+fed, s)`` and ``frozen_bytes(cfg, itemsize)``.
+
 Counts are per sequence of ``s`` tokens and follow the configuration
 file, never the program's code. Only matrix products are counted, at 2
 operations per multiply-add; normalisation, softmax and other
@@ -22,73 +30,31 @@ triangle only.
 """
 from __future__ import annotations
 
+import importlib.util
+from pathlib import Path
 
-def _dims(cfg):
-    d = cfg["hidden_size"]
-    h = cfg["num_attention_heads"]
-    kv = cfg.get("num_key_value_heads", h)
-    return d, h, kv, d // h, cfg["intermediate_size"]
+COUNTS = Path(__file__).resolve().parent / "counts"
 
-
-def _causal(cfg) -> bool:
-    return cfg["family"] == "decoder"
+_LOADED = {}
 
 
-def _pairs(cfg, s: int) -> int:
-    return s * (s + 1) // 2 if _causal(cfg) else s * s
+def load_counts(name: str, directory: Path = COUNTS):
+    """The module ``<directory>/<name>.py``, loaded once by path."""
+    path = Path(directory) / f"{name}.py"
+    if path not in _LOADED:
+        if not path.exists():
+            raise FileNotFoundError(f"no count module {path}")
+        spec = importlib.util.spec_from_file_location(
+            "counts_" + name.replace(".", "_").replace("-", "_"), path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        _LOADED[path] = mod
+    return _LOADED[path]
 
 
-def _lora_io(cfg):
-    """(input width, output width) of each adapted projection."""
-    d, h, kv, e, _ = _dims(cfg)
-    io = {"q": (d, h * e), "k": (d, kv * e), "v": (d, kv * e),
-          "o": (h * e, d)}
-    return [io[t] for t in cfg["lora"]["targets"]]
-
-
-def lora_forward(cfg, s: int) -> int:
-    r = cfg["lora"]["rank"]
-    return sum(2 * s * (i * r + r * o) for i, o in _lora_io(cfg))
-
-
-def mlp_forward(cfg, s: int) -> int:
-    d, _, _, _, f = _dims(cfg)
-    mats = 2 if cfg["family"] == "encoder" else 3
-    return mats * 2 * s * d * f
-
-
-def attention_core(cfg, s: int) -> int:
-    """Scores plus probability-times-values, all heads."""
-    d, h, _, e, _ = _dims(cfg)
-    return 2 * (2 * _pairs(cfg, s) * e * h)
-
-
-def block_forward(cfg, s: int) -> int:
-    d, h, kv, e, _ = _dims(cfg)
-    qkv = 2 * s * d * e * (h + 2 * kv)
-    out = 2 * s * h * e * d
-    return qkv + out + attention_core(cfg, s) + mlp_forward(cfg, s) \
-        + lora_forward(cfg, s)
-
-
-def block_backward(cfg, s: int, lowest: bool = False) -> int:
-    """Activation gradients (each frozen product once more) and the
-    adapters' weight gradients (their products twice more). The lowest
-    block skips the input gradient of its q/k/v projections and the
-    attention gradients no adapter needs."""
-    d, h, kv, e, _ = _dims(cfg)
-    targets = set(cfg["lora"]["targets"])
-    out = 2 * s * h * e * d
-    one = 2 * _pairs(cfg, s) * e * h        # one attention product
-    if lowest:
-        attn = one * (("q" in targets or "k" in targets)
-                      + ("v" in targets) + ("q" in targets)
-                      + ("k" in targets))
-        qkv = 0
-    else:
-        attn = 4 * one
-        qkv = 2 * s * d * e * (h + 2 * kv)
-    return qkv + out + attn + mlp_forward(cfg, s) + 2 * lora_forward(cfg, s)
+def family(cfg):
+    """The count module of the configuration's ``family``."""
+    return load_counts(cfg["family"])
 
 
 def channel_forward(cfg, fed, s: int) -> int:
@@ -99,30 +65,18 @@ def channel_forward(cfg, fed, s: int) -> int:
     return 2 * rot + s * d * fed["sketch_y"]
 
 
-def head_forward(cfg, fed, s: int) -> int:
-    d = cfg["hidden_size"]
-    if cfg["family"] == "encoder":
-        return 2 * d * d + 2 * d * fed["num_classes"]
-    return 2 * s * d * cfg["vocab_size"]
-
-
-def head_backward(cfg, fed, s: int) -> int:
-    """Encoder: pooler and classifier are trained (input and weight
-    gradients). Decoder: the tied head is frozen (input gradient only)."""
-    mult = 2 if cfg["family"] == "encoder" else 1
-    return mult * head_forward(cfg, fed, s)
-
-
 def train_sequence(cfg, fed, s: int) -> int:
     """One sequence through one local step: split forward with the
-    channel, and the backward down to block 0."""
-    layers = cfg["num_hidden_layers"]
+    channel, and the backward down to the lowest adapted layer, layer
+    0."""
+    m = family(cfg)
+    layers = range(cfg["num_hidden_layers"])
     crossings = 2 if fed.get("use_channel", True) else 0
-    fwd = layers * block_forward(cfg, s) + head_forward(cfg, fed, s) \
+    fwd = sum(m.block_forward(cfg, s, i) for i in layers) \
+        + m.head_forward(cfg, fed, s) \
         + crossings * channel_forward(cfg, fed, s)
-    bwd = (layers - 1) * block_backward(cfg, s) \
-        + block_backward(cfg, s, lowest=True) \
-        + head_backward(cfg, fed, s) \
+    bwd = sum(m.block_backward(cfg, s, i, lowest=(i == 0)) for i in layers) \
+        + m.head_backward(cfg, fed, s) \
         + crossings * channel_forward(cfg, fed, s)
     return fwd + bwd
 
@@ -130,20 +84,10 @@ def train_sequence(cfg, fed, s: int) -> int:
 def forward_sequence(cfg, fed, s: int, logits: bool = True) -> int:
     """One sequence through the unsplit forward (eval; probe without
     the logits)."""
-    out = cfg["num_hidden_layers"] * block_forward(cfg, s)
-    return out + (head_forward(cfg, fed, s) if logits else 0)
-
-
-def frozen_bytes(cfg, itemsize: int = 4) -> int:
-    """Bytes of the frozen weights a local step has to read at least
-    once: every block, and the tied output head of a decoder."""
-    d, h, kv, e, f = _dims(cfg)
-    mats = 2 if cfg["family"] == "encoder" else 3
-    per_block = d * e * (h + 2 * kv) + h * e * d + mats * d * f
-    total = cfg["num_hidden_layers"] * per_block
-    if cfg["family"] == "decoder":
-        total += cfg["vocab_size"] * d
-    return total * itemsize
+    m = family(cfg)
+    out = sum(m.block_forward(cfg, s, i)
+              for i in range(cfg["num_hidden_layers"]))
+    return out + (m.head_forward(cfg, fed, s) if logits else 0)
 
 
 def dispatch_least_seconds(cfg, fed, steps: int, clients: int, batch: int,
@@ -152,7 +96,7 @@ def dispatch_least_seconds(cfg, fed, steps: int, clients: int, batch: int,
     ``(seconds, bound)`` with bound ``"compute"`` or ``"memory"``."""
     ops = steps * clients * batch * train_sequence(cfg, fed, s)
     t_ops = ops / peak_flops
-    t_mem = steps * frozen_bytes(cfg) / peak_bw
+    t_mem = steps * family(cfg).frozen_bytes(cfg) / peak_bw
     return (t_ops, "compute") if t_ops >= t_mem else (t_mem, "memory")
 
 

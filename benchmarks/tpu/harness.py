@@ -11,6 +11,8 @@ traffic mix or one metric lives in its own file and is found by name:
 - ``configs/<config>.json``: the configuration as run, with its plain
   reference ``configs/<config>.ref.py`` and its limits
   ``configs/<config>.limits.json``;
+- ``counts/<family>.py``: the operations and bytes of the layers and
+  head of the configuration's ``family`` (``flops.py``);
 - ``traffic/<traffic>.json``: the job's parameters;
 - ``metrics/<metric>.py``: a reader ``read(ctx)`` returning a number,
   or ``None`` when it finds nothing to read.
@@ -153,8 +155,21 @@ def fed_settings(cell: Cell) -> dict:
     return kw
 
 
+def _attribute(obj, dotted: str):
+    """``obj.a.b`` for ``"a.b"``; ``None`` where the path is not
+    there."""
+    for name in dotted.split("."):
+        obj = getattr(obj, name, None)
+    return obj
+
+
 def check_model(fed, config: dict) -> None:
-    """The program's resolved architecture is the configuration file's."""
+    """The program's resolved architecture is the configuration file's.
+
+    Seven checks hold for every file. A file's ``program_checks``,
+    ``{"<dotted ArchConfig attribute>": "<config key>"}``, adds to them
+    or replaces one, e.g. ``{"moe.expert_d_ff":
+    "moe_intermediate_size"}``."""
     c = fed.cfg
     want = {"num_layers": config["num_hidden_layers"],
             "d_model": config["hidden_size"],
@@ -164,8 +179,10 @@ def check_model(fed, config: dict) -> None:
             "d_ff": config["intermediate_size"],
             "vocab_size": config["vocab_size"],
             "param_dtype": config["torch_dtype"]}
+    for attr, key in config.get("program_checks", {}).items():
+        want[attr] = config[key]
     lora = config["lora"]
-    got = {k: getattr(c, k) for k in want}
+    got = {k: _attribute(c, k) for k in want}
     got_lora = (c.lora.rank, c.lora.alpha, tuple(c.lora.targets))
     if got != want or got_lora != (lora["rank"], lora["alpha"],
                                    tuple(lora["targets"])):

@@ -1,6 +1,6 @@
 """Self time of the traced job's divergence matrix: the ``profile.kl``
-span (one eager symmetric KL per client pair, each read on the host)
-less the time its child spans cover."""
+span (one jitted program over every client pair, and one host read of
+the whole matrix) less the time its child spans cover."""
 import program_spans as ps
 
 

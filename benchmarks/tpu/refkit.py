@@ -18,8 +18,14 @@ bfloat16 at default precision, the next precision below what the
 configurations state.
 
 A model file (``configs/<name>.ref.py``) supplies ``param_tree(cfg)``,
-``embed``, ``block``, ``head`` and ``per_example_loss``. This module
-holds what every model shares: parameter init, the channel, the split
+``embed``, ``head`` and ``per_example_loss``, and runs its layers one
+of two ways. A uniform stack gives ``block``: this module scans it over
+``frozen["blocks"]`` and ``lora["blocks"]``, each leaf stacked by
+layer. Any other stack (a dense first layer before expert layers, say)
+gives ``run_layers(num, cfg, frozen, lora, x, lo, hi, cut)``, which runs
+layers ``[lo, hi)`` and calls ``cut(x, i)`` before layer ``i``: ``cut``
+applies the channel where ``i`` is a split point. This module holds
+what every model shares: parameter init, the channel, the split
 forward, local SGD and product-space aggregation.
 """
 from __future__ import annotations
@@ -81,37 +87,26 @@ def ones(shape):
     return ("ones", tuple(shape), 0.0)
 
 
-def _leaves(tree, path=()):
-    """(path, leaf) pairs in sorted-key order; empty dicts give none."""
-    if isinstance(tree, dict):
-        out = []
-        for k in sorted(tree):
-            out += _leaves(tree[k], path + (k,))
-        return out
-    return [(path, tree)]
-
-
-def _set(tree, path, value):
-    for k in path[:-1]:
-        tree = tree.setdefault(k, {})
-    tree[path[-1]] = value
+def _is_init(x) -> bool:
+    return isinstance(x, tuple) and len(x) == 3 and isinstance(x[0], str)
 
 
 def init_params(table, seed: int):
-    """Materialise ``table`` (nested dict of init tuples) in float32:
-    one key per leaf from ``jax.random.split(PRNGKey(seed), n)``."""
-    leaves = _leaves(table)
+    """Materialise ``table`` (a tree of dicts and lists of init tuples)
+    in float32: one key per leaf from ``jax.random.split(PRNGKey(seed),
+    n)``, the leaves in the tree's flattening order (dict keys sorted,
+    lists in order)."""
+    leaves, tree = jax.tree_util.tree_flatten(table, is_leaf=_is_init)
     keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
-    out = {}
-    for (path, (kind, shape, std)), key in zip(leaves, keys):
+    out = []
+    for (kind, shape, std), key in zip(leaves, keys):
         if kind == "zeros":
-            v = jnp.zeros(shape, jnp.float32)
+            out.append(jnp.zeros(shape, jnp.float32))
         elif kind == "ones":
-            v = jnp.ones(shape, jnp.float32)
+            out.append(jnp.ones(shape, jnp.float32))
         else:
-            v = jax.random.normal(key, shape, jnp.float32) * std
-        _set(out, path, v)
-    return out
+            out.append(jax.random.normal(key, shape, jnp.float32) * std)
+    return jax.tree_util.tree_unflatten(tree, out)
 
 
 def cast(tree, dtype):
@@ -248,11 +243,20 @@ class Reference:
 
     # -- forward ------------------------------------------------------------
     def _blocks(self, num, frozen, lora, x, lo, hi, chan_at, chan):
-        """Blocks [lo, hi) in a scan over layers; the channel runs
-        before every layer listed in ``chan_at`` (traced ints)."""
-        def body(x, i):
+        """Layers [lo, hi); the channel runs before every layer listed
+        in ``chan_at`` (traced ints). A uniform stack runs in a scan
+        over layers, any other through the model's ``run_layers``."""
+        def cut(x, i):
             for at in chan_at:
                 x = jax.lax.cond(i == at, chan, lambda t: t, x)
+            return x
+
+        run_layers = getattr(self.model, "run_layers", None)
+        if run_layers is not None:
+            return run_layers(num, self.cfg, frozen, lora, x, lo, hi, cut)
+
+        def body(x, i):
+            x = cut(x, i)
             p = jax.tree_util.tree_map(lambda a: a[i], frozen["blocks"])
             lp = jax.tree_util.tree_map(lambda a: a[i], lora["blocks"])
             return self.model.block(num, self.cfg, p, lp, x), None
@@ -350,7 +354,9 @@ def weighted_mean(trees, w):
 def product_mean(trees, weights, precision=jax.lax.Precision.HIGHEST):
     """Weighted mean of LoRA trees in weight-delta space: per layer
     ``A <- mean A``, ``B <- mean B + A^+ (mean(A_i B_i) - A mean B)``
-    with ``A^+ = (A^T A + 1e-8 I)^-1 A^T``; other leaves averaged."""
+    with ``A^+ = (A^T A + 1e-8 I)^-1 A^T``; other leaves averaged.
+    Adapters under a ``blocks`` key are stacked by layer; elsewhere (a
+    list of leading layers, say) each pair is one layer's."""
     w = np.asarray(weights, np.float64)
     w = [float(x) for x in w / w.sum()]
     if len(trees) == 1:
@@ -358,22 +364,27 @@ def product_mean(trees, weights, precision=jax.lax.Precision.HIGHEST):
                                       trees[0])
     mean = weighted_mean(trees, w)
 
-    def refit(node, nodes):
+    def refit(node, nodes, stacked):
+        if isinstance(node, (list, tuple)):
+            return type(node)(refit(v, [n[i] for n in nodes], stacked)
+                              for i, v in enumerate(node))
         if not isinstance(node, dict):
             return node
-        out = {k: refit(v, [n[k] for n in nodes]) for k, v in node.items()}
+        out = {k: refit(v, [n[k] for n in nodes], stacked or k == "blocks")
+               for k, v in node.items()}
+        lead = (lambda a: a) if stacked else (lambda a: a[None])
         for key in node:
             if not key.endswith("_a") or key[:-2] + "_b" not in node:
                 continue
             t = key[:-2]
-            a_m, b_m = out[t + "_a"], out[t + "_b"]
+            a_m, b_m = lead(out[t + "_a"]), lead(out[t + "_b"])
             layers, r = a_m.shape[0], a_m.shape[-1]
             am = a_m.reshape(layers, -1, r)
             bm = b_m.reshape(layers, r, -1)
             dw = sum(wi * jnp.einsum(
                 "lmr,lrk->lmk",
-                n[t + "_a"].astype(jnp.float32).reshape(layers, -1, r),
-                n[t + "_b"].astype(jnp.float32).reshape(layers, r, -1),
+                lead(n[t + "_a"]).astype(jnp.float32).reshape(layers, -1, r),
+                lead(n[t + "_b"]).astype(jnp.float32).reshape(layers, r, -1),
                 precision=precision) for wi, n in zip(w, nodes))
             res = dw - jnp.einsum("lmr,lrk->lmk", am, bm,
                                   precision=precision)
@@ -381,7 +392,7 @@ def product_mean(trees, weights, precision=jax.lax.Precision.HIGHEST):
                 + 1e-8 * jnp.eye(r, dtype=jnp.float32)
             rhs = jnp.einsum("lmr,lmk->lrk", am, res, precision=precision)
             out[t + "_b"] = (bm + jnp.linalg.solve(gram, rhs)
-                             ).reshape(b_m.shape)
+                             ).reshape(out[t + "_b"].shape)
         return out
 
-    return refit(mean, list(trees))
+    return refit(mean, list(trees), False)
