@@ -212,6 +212,109 @@ def _pad_axis1(arr: np.ndarray, pad: int) -> np.ndarray:
     return np.concatenate([arr, z], axis=1)
 
 
+def split_label(split: Split) -> str:
+    return f"p{split.p}q{split.q}o{split.o}"
+
+
+def cut_members(members: List[int], cap: int) -> List[List[int]]:
+    """``members`` in order, as ``ceil(n / cap)`` cuts of near-equal
+    size (the first ``n % k`` of the ``k`` cuts one longer): 20 at cap 8
+    go as 7 + 7 + 6, which the ladder pads not at all."""
+    k = -(-len(members) // cap)
+    q, r = divmod(len(members), k)
+    ends = [i * q + min(i, r) for i in range(k + 1)]
+    return [members[a:b] for a, b in zip(ends, ends[1:])]
+
+
+def compile_refused(err: BaseException) -> bool:
+    """Whether ``err`` is the XLA compiler's refusal of a program for
+    want of device memory (on a TPU, ``RESOURCE_EXHAUSTED: XLA:TPU
+    compile permanent error. Ran out of memory in memory space hbm``).
+    Nothing has run then.  An allocation that fails once a program is
+    loaded or running (``Error allocating device buffer``, ``Error
+    loading program``) is not one: its inputs may be donated already,
+    so it stays an error."""
+    msg = str(err)
+    return (isinstance(err, jax.errors.JaxRuntimeError)
+            and msg.startswith("RESOURCE_EXHAUSTED") and "compile" in msg)
+
+
+def _program_bytes(m) -> int:
+    """Device bytes of a compiled program from its memory analysis:
+    arguments, outputs not aliased to them, and temporaries."""
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+
+
+def fit_clients(compile_cut, refused: int, refusal: Exception,
+                multiple: int = 1, bytes_limit: Optional[int] = None,
+                on_refusal=lambda n: None) -> int:
+    """The largest :data:`BUCKET_LADDER` size below ``refused`` (and a
+    multiple of ``multiple``) whose round program the compiler accepts.
+
+    ``compile_cut(n)`` compiles the program for ``n`` clients and
+    returns its memory analysis, or raises the compiler's refusal
+    (``on_refusal(n)`` then notes it).  With the device's
+    ``bytes_limit`` the first guess is linear in the clients, from the
+    two smallest sizes' analyses: the arguments with no client axis (the
+    frozen weights) are fixed, and every client adds what the second
+    size adds over the first.  (Their temporaries also hold a part that
+    does not grow with the clients; the compiler finds room for it at
+    larger sizes, where the analysis of an accepted program can read
+    more than the limit, so the guess leaves it out.)  The guess is
+    confirmed by compiling it, and each refusal steps one size down.
+    Without a limit the search starts one size below ``refused``.
+    ``refusal`` is raised again when no size below ``refused``
+    compiles.
+    """
+    sizes = [s for s in BUCKET_LADDER if s < refused and s % multiple == 0]
+    analyses = {}
+
+    def compiles(n: int) -> bool:
+        try:
+            analyses[n] = compile_cut(n)
+            return True
+        except jax.errors.JaxRuntimeError as e:
+            if not compile_refused(e):
+                raise
+            on_refusal(n)
+            return False
+
+    i = len(sizes) - 1
+    if bytes_limit and len(sizes) > 2:
+        s1, s2 = sizes[:2]
+        if not compiles(s1):
+            raise refusal
+        if not compiles(s2):
+            return s1
+        m1, m2 = analyses[s1], analyses[s2]
+        if m1 is not None and m2 is not None:
+            per = (_program_bytes(m2) - _program_bytes(m1)) / (s2 - s1)
+            fixed = m1.argument_size_in_bytes - s1 * (
+                m2.argument_size_in_bytes - m1.argument_size_in_bytes
+            ) / (s2 - s1)
+            guess = (bytes_limit - fixed) / per if per > 0 else refused
+            i = max([j for j, s in enumerate(sizes) if s <= guess],
+                    default=0)
+    for s in reversed(sizes[:i + 1]):
+        if s in analyses or compiles(s):
+            return s
+    raise refusal
+
+
+def _cut_shapes(shapes, n: int):
+    """Round-program argument shapes with ``n`` clients: the client axis
+    is axis 0 of the LoRA and SS-OP stacks and axis 1 of the batches."""
+    frozen, lora, ssop, anchor, toks, labs, wts = shapes
+
+    def resize(a, axis):
+        shape = a.shape[:axis] + (n,) + a.shape[axis + 1:]
+        return jax.ShapeDtypeStruct(shape, a.dtype, sharding=a.sharding)
+    lead = lambda tree: jax.tree_util.tree_map(lambda a: resize(a, 0), tree)
+    return (frozen, lead(lora), lead(ssop), anchor,
+            resize(toks, 1), resize(labs, 1), resize(wts, 1))
+
+
 # ---------------------------------------------------------------------------
 # engine
 # ---------------------------------------------------------------------------
@@ -268,6 +371,9 @@ class BatchedEngine:
             # replicate them once up front
             self.frozen = jax.device_put(frozen, self._replicate)
         self._round_fns: Dict = {}
+        # (split, prox, steps, per-row token shape) -> the most clients
+        # one round program holds, where the compiler refused more
+        self._caps: Dict = {}
         if tm.enabled():
             tm.set_gauge("engine.donate_buffers", float(self.donate),
                          platform=self.platform)
@@ -334,6 +440,118 @@ class BatchedEngine:
         how many distinct cohort shapes each has specialized for."""
         return {k: fn._cache_size() for k, fn in self._round_fns.items()}
 
+    def _compile_cut(self, fn, args):
+        """Compile the round program ``fn`` ahead of time for ``args``
+        (abstract shapes) and return its memory analysis; raises the
+        compiler's refusal as a call would."""
+        return fn.lower(*args).compile().memory_analysis()
+
+    def _bytes_limit(self) -> Optional[int]:
+        """The device memory a program may take, where the device says
+        (a TPU does; the CPU does not)."""
+        dev = (self.mesh.devices.flat[0] if self.mesh is not None
+               else jax.devices()[0])
+        return (dev.memory_stats() or {}).get("bytes_limit")
+
+    def _fit(self, split: Split, prox: bool, shapes, refused: int,
+             refusal: Exception) -> int:
+        """The largest cut of a bucket whose round program compiles, once
+        the compiler refused ``refused`` clients (:func:`fit_clients`)."""
+        fn = self._round_fns[(split, prox)]
+        lbl = split_label(split)
+        with tm.span("engine.fit", split=lbl, refused=refused) as sp:
+            cap = fit_clients(
+                lambda n: self._compile_cut(fn, _cut_shapes(shapes, n)),
+                refused, refusal, self.n_shards, self._bytes_limit(),
+                lambda n: tm.inc("engine.compile_refusals", 1, split=lbl,
+                                 bucket=n))
+            sp.set(cap=cap)
+        return cap
+
+    def _stack(self, theta, members: List[int], per_client: bool,
+               channels, batches, prox_anchor):
+        """Pad and stack one dispatch's batches, LoRA and SS-OP trees and
+        copy them to the device: ``(size, round-program arguments)``."""
+        with tm.span("engine.stack", n_clients=len(members)):
+            toks, labs, wts = stack_padded_batches(
+                [batches[n] for n in members], self.batch_size)
+            n_real = len(members)
+            size = (bucket_size(n_real, self.n_shards)
+                    if self.pad_cohorts
+                    else -(-n_real // self.n_shards) * self.n_shards)
+            if size > n_real:
+                pad = size - n_real
+                toks = _pad_axis1(toks, pad)
+                labs = _pad_axis1(labs, pad)
+                wts = _pad_axis1(wts, pad)   # zero weights: inert rows
+            if per_client:
+                # per-client starting points; phantom rows repeat the
+                # last member (zero weights keep them inert)
+                trees = [theta[n] for n in members]
+                trees += [theta[members[-1]]] * (size - n_real)
+                lora_stack = stack_trees(trees)
+            else:
+                lora_stack = broadcast_tree(theta, size)
+            ssop_stack = None
+            if self.use_channel and self.use_ssop:
+                ssops = [channels[n].ssop for n in members]
+                ssops += [ssops[-1]] * (size - n_real)   # phantom rows
+                ssop_stack = stack_ssops(ssops)
+            if self.mesh is not None:
+                lora_stack = jax.device_put(lora_stack, self._shard_clients)
+                if ssop_stack is not None:
+                    ssop_stack = jax.device_put(ssop_stack,
+                                                self._shard_clients)
+                toks, labs, wts = jax.device_put((toks, labs, wts),
+                                                 self._shard_batches)
+            else:
+                toks, labs, wts = (jnp.asarray(toks), jnp.asarray(labs),
+                                   jnp.asarray(wts))
+        return size, (self.frozen, lora_stack, ssop_stack, prox_anchor,
+                      toks, labs, wts)
+
+    def _dispatch(self, split: Split, members: List[int], size: int, args,
+                  cap: Optional[int] = None):
+        """Enqueue one round program; ``cap`` marks a cut of a bucket."""
+        prox = args[3] is not None
+        fn = self._round_fn(split, prox)
+        tel = tm.get()
+        before = fn._cache_size() if tel is not None else 0
+        # the call only enqueues the round on an asynchronous
+        # backend: the host waits for it in engine.fetch
+        with tm.span("engine.dispatch"):
+            t0 = time.perf_counter()
+            out_stack, losses = fn(*args)
+            dur = time.perf_counter() - t0
+        if tel is not None:
+            # the jit cache growing across this dispatch means a
+            # fresh trace+compile for this (split, cohort-bucket)
+            # shape; steady state stays at one executable per
+            # (split, bucket)
+            lbl = split_label(split)
+            compiled = fn._cache_size() > before
+            if compiled:
+                tm.inc("engine.jit_compiles", 1, split=lbl, bucket=size,
+                       prox=prox)
+            tm.observe("engine.dispatch_s", dur, compiled=compiled)
+            tm.inc("engine.clients", len(members))
+            tm.inc("engine.phantom_rows", size - len(members))
+            # placement as dispatched: how many pieces the client
+            # axis of each stack is really split into (min/max over
+            # every dispatch)
+            tm.observe("engine.client_axis_shards",
+                       _axis_pieces(jax.tree_util.tree_leaves(args[1])[0],
+                                    0), stack="lora")
+            tm.observe("engine.client_axis_shards",
+                       _axis_pieces(args[4], 1), stack="batch")
+            tm.set_gauge("engine.compile_cache", fn._cache_size(),
+                         split=lbl, prox=prox)
+            if cap is not None:
+                tm.inc("engine.cut_dispatches", 1, split=lbl, size=size)
+                tm.set_gauge("engine.fit_clients", cap, split=lbl,
+                             prox=prox)
+        return members, out_stack, losses
+
     # -- public API --------------------------------------------------------
     def run_clients(self, theta, clients: Sequence[int],
                     splits: Dict[int, Split], channels: Dict[int, Channel],
@@ -367,90 +585,58 @@ class BatchedEngine:
         With a mesh, bucket sizes are additionally multiples of the
         client-axis extent and every client-stacked input is placed with
         its leading axis sharded across the mesh.
+
+        A bucket goes whole in one program unless the compiler refuses
+        that program for want of device memory.  Then the engine finds
+        the largest ladder size that compiles (:func:`fit_clients`),
+        keeps it for that (split, prox, per-client shapes) for its
+        lifetime, and dispatches such buckets in :func:`cut_members`
+        cuts of at most that many clients.  Each client's result is its
+        own row of a program either way.
         """
         per_client = (is_client_map(theta) if per_client_theta is None
                       else per_client_theta)
+        prox = prox_anchor is not None
         buckets: Dict[Split, List[int]] = {}
         for n in clients:
             buckets.setdefault(splits[n], []).append(n)
-        if self.mesh is not None and prox_anchor is not None:
+        if self.mesh is not None and prox:
             prox_anchor = jax.device_put(prox_anchor, self._replicate)
+        stack = lambda members: self._stack(theta, members, per_client,
+                                            channels, batches, prox_anchor)
 
         pending = []
         for split, members in buckets.items():
-            # padding and stacking the batches, LoRA and SS-OP trees, and
-            # their copies to the device
-            with tm.span("engine.stack", n_clients=len(members)):
-                toks, labs, wts = stack_padded_batches(
-                    [batches[n] for n in members], self.batch_size)
-                n_real = len(members)
-                size = (bucket_size(n_real, self.n_shards)
-                        if self.pad_cohorts
-                        else -(-n_real // self.n_shards) * self.n_shards)
-                if size > n_real:
-                    pad = size - n_real
-                    toks = _pad_axis1(toks, pad)
-                    labs = _pad_axis1(labs, pad)
-                    wts = _pad_axis1(wts, pad)   # zero weights: inert rows
-                if per_client:
-                    # per-client starting points; phantom rows repeat the
-                    # last member (zero weights keep them inert)
-                    trees = [theta[n] for n in members]
-                    trees += [theta[members[-1]]] * (size - n_real)
-                    lora_stack = stack_trees(trees)
-                else:
-                    lora_stack = broadcast_tree(theta, size)
-                ssop_stack = None
-                if self.use_channel and self.use_ssop:
-                    ssops = [channels[n].ssop for n in members]
-                    ssops += [ssops[-1]] * (size - n_real)   # phantom rows
-                    ssop_stack = stack_ssops(ssops)
-                if self.mesh is not None:
-                    lora_stack = jax.device_put(lora_stack,
-                                                self._shard_clients)
-                    if ssop_stack is not None:
-                        ssop_stack = jax.device_put(ssop_stack,
-                                                    self._shard_clients)
-                    toks, labs, wts = jax.device_put(
-                        (toks, labs, wts), self._shard_batches)
-                else:
-                    toks, labs, wts = (jnp.asarray(toks), jnp.asarray(labs),
-                                       jnp.asarray(wts))
-            fn = self._round_fn(split, prox_anchor is not None)
-            tel = tm.get()
-            before = fn._cache_size() if tel is not None else 0
-            # the call only enqueues the round on an asynchronous
-            # backend: the host waits for it in engine.fetch
-            with tm.span("engine.dispatch"):
-                t0 = time.perf_counter()
-                out_stack, losses = fn(self.frozen, lora_stack, ssop_stack,
-                                       prox_anchor, toks, labs, wts)
-                dur = time.perf_counter() - t0
-            if tel is not None:
-                # the jit cache growing across this dispatch means a
-                # fresh trace+compile for this (split, cohort-bucket)
-                # shape; steady state stays at one executable per
-                # (split, bucket)
-                lbl = f"p{split.p}q{split.q}o{split.o}"
-                prox_l = prox_anchor is not None
-                compiled = fn._cache_size() > before
-                if compiled:
-                    tm.inc("engine.jit_compiles", 1, split=lbl,
-                           bucket=size, prox=prox_l)
-                tm.observe("engine.dispatch_s", dur, compiled=compiled)
-                tm.inc("engine.clients", n_real)
-                tm.inc("engine.phantom_rows", size - n_real)
-                # placement as dispatched: how many pieces the client
-                # axis of each stack is really split into (min/max over
-                # every dispatch)
-                tm.observe("engine.client_axis_shards",
-                           _axis_pieces(jax.tree_util.tree_leaves(
-                               lora_stack)[0], 0), stack="lora")
-                tm.observe("engine.client_axis_shards",
-                           _axis_pieces(toks, 1), stack="batch")
-                tm.set_gauge("engine.compile_cache", fn._cache_size(),
-                             split=lbl, prox=prox_l)
-            pending.append((members, out_stack, losses))
+            first = batches[members[0]]
+            key = (split, prox, len(first), first[0][0].shape[1:])
+            cap = self._caps.get(key)
+            if cap is None or len(members) <= cap:
+                size, args = stack(members)
+                try:
+                    pending.append(self._dispatch(split, members, size,
+                                                  args))
+                    continue
+                except jax.errors.JaxRuntimeError as e:
+                    if not compile_refused(e):
+                        raise
+                    # kept without its traceback, whose frames hold
+                    # the bucket's stacks
+                    refusal = jax.errors.JaxRuntimeError(str(e))
+                # nothing ran, so nothing was donated; the whole
+                # bucket's stacks leave the device before its cuts run
+                shapes = jax.tree_util.tree_map(
+                    lambda a: jax.ShapeDtypeStruct(
+                        a.shape, a.dtype,
+                        sharding=getattr(a, "sharding", None)),
+                    args)
+                del args
+                tm.inc("engine.compile_refusals", 1,
+                       split=split_label(split), bucket=size)
+                cap = self._caps[key] = self._fit(split, prox, shapes, size,
+                                                  refusal)
+            for cut in cut_members(members, cap):
+                pending.append(self._dispatch(split, cut, *stack(cut),
+                                              cap=cap))
 
         # one host sync for every bucket's (steps, N) loss array
         with tm.span("engine.fetch"):

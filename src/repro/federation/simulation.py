@@ -475,9 +475,13 @@ class Federation:
     def profile_clients(self):
         """Phase 1: warmup locally, fingerprint, trust, cluster.
 
-        On the batched backend the warmup of all clients runs as one
-        compiled vmap/scan round (they share the default split) and the
-        probe forwards batch through a single vmapped jit call.
+        On the batched backend the warmup of all clients goes through
+        the engine as one split bucket (they share the default split):
+        one vmap/scan round program, or, where the compiler refuses that
+        many clients at once for want of device memory, several cuts of
+        the bucket.  Each client is its own row either way, so the
+        warmed adapters do not depend on the cut.  The probe forwards
+        batch through a single vmapped jit call.
         """
         fed = self.fed
         iters = {n: infinite_batches(self.data[n].tokens,

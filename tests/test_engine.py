@@ -149,3 +149,197 @@ def test_weighted_loss_padding_matches_unpadded():
         lambda lp: weighted_split_loss(cfg, frozen, lp, padded, split))(lora)
     assert abs(float(l_ref) - float(l_pad)) <= 1e-6
     assert _max_tree_diff(g_ref, g_pad) <= 1e-5
+
+
+# ---------------------------------------------------------------------------
+# buckets cut to what the compiler accepts
+# ---------------------------------------------------------------------------
+
+#: the v5e compiler's words for a program that does not fit, and the
+#: runtime's for an allocation that fails once a program is loaded
+REFUSAL = ("RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out "
+           "of memory in memory space hbm. Used 22.18G of 15.75G hbm.")
+RUNTIME_OOM = ("RESOURCE_EXHAUSTED: Error allocating device buffer: "
+               "Attempting to allocate 1.00G. That was not possible. "
+               "There are 512.00M free.")
+
+
+def refuse_above(monkeypatch, cap, message=REFUSAL):
+    """Make every round program of more than ``cap`` clients fail with
+    ``message``, both where the engine dispatches it and where its fit
+    compiles it ahead of time."""
+    from repro.federation.engine import BatchedEngine
+    round_fn, compile_cut = BatchedEngine._round_fn, BatchedEngine._compile_cut
+
+    def check(args):
+        if args[4].shape[1] > cap:
+            raise jax.errors.JaxRuntimeError(message)
+
+    def refusing_round_fn(self, split, prox):
+        fn = round_fn(self, split, prox)
+
+        def call(*args):
+            check(args)
+            return fn(*args)
+        call._cache_size = fn._cache_size
+        return call
+
+    def refusing_compile_cut(self, fn, args):
+        check(args)
+        return compile_cut(self, fn, args)
+    monkeypatch.setattr(BatchedEngine, "_round_fn", refusing_round_fn)
+    monkeypatch.setattr(BatchedEngine, "_compile_cut", refusing_compile_cut)
+
+
+@pytest.mark.parametrize("n,cap,sizes", [(20, 8, [7, 7, 6]), (6, 2, [2, 2, 2]),
+                                         (5, 8, [5]), (9, 4, [3, 3, 3])])
+def test_cut_members_keeps_order_in_near_equal_cuts(n, cap, sizes):
+    from repro.federation.engine import cut_members
+    members = [10 * i for i in range(n)]
+    cuts = cut_members(members, cap)
+    assert [len(c) for c in cuts] == sizes
+    assert [m for c in cuts for m in c] == members
+
+
+@pytest.mark.parametrize("message,refused", [
+    (REFUSAL, True), (RUNTIME_OOM, False),
+    ("RESOURCE_EXHAUSTED: TPU backend error: Error loading program: "
+     "Attempting to reserve 12.5G at the bottom of memory.", False),
+    ("INTERNAL: compile failed", False)])
+def test_compile_refused_is_the_compilers_refusal_alone(message, refused):
+    from repro.federation.engine import compile_refused
+    assert compile_refused(jax.errors.JaxRuntimeError(message)) is refused
+    assert not compile_refused(RuntimeError(message))
+
+
+class _Analysis:
+    """A memory analysis of ``n`` clients: 4 GB of weights, 16 MB of
+    arguments and 1.4 GB of temporaries per client over a 4.5 GB part
+    that does not grow (the shape of OLMo-1B's warm-up on a v5e)."""
+
+    def __init__(self, n):
+        self.argument_size_in_bytes = 4.0e9 + n * 16e6
+        self.output_size_in_bytes = self.alias_size_in_bytes = n * 16e6
+        self.temp_size_in_bytes = 4.5e9 + n * 1.4e9
+
+
+@pytest.mark.parametrize("limit,accepts,cap,tried", [
+    (16.9e9, 8, 8, [1, 2, 8]),            # the guess compiles
+    (16.9e9, 7, 7, [1, 2, 8, 7]),         # one refusal, one size down
+    (None, 5, 5, [16, 14, 12, 10, 8, 7, 6, 5]),   # no limit: step down
+    (3.0e9, 8, 1, [1, 2]),                # the guess is below two
+    (16.9e9, 1, 1, [1, 2]),               # two clients are refused
+])
+def test_fit_clients_guesses_then_steps_down(limit, accepts, cap, tried):
+    from repro.federation.engine import fit_clients
+    compiled, refusals = [], []
+
+    def compile_cut(n):
+        compiled.append(n)
+        if n > accepts:
+            raise jax.errors.JaxRuntimeError(REFUSAL)
+        return _Analysis(n)
+    got = fit_clients(compile_cut, 20, None, 1, limit, refusals.append)
+    assert (got, compiled) == (cap, tried)
+    assert refusals == [n for n in tried if n > accepts]
+
+
+def test_fit_clients_raises_what_is_not_a_refusal():
+    from repro.federation.engine import fit_clients
+    refusal = jax.errors.JaxRuntimeError(REFUSAL)
+
+    def compile_cut(n):
+        raise jax.errors.JaxRuntimeError(RUNTIME_OOM if n < 4 else REFUSAL)
+    with pytest.raises(jax.errors.JaxRuntimeError, match="allocating"):
+        fit_clients(compile_cut, 10, refusal)
+    with pytest.raises(jax.errors.JaxRuntimeError, match="compile"):
+        fit_clients(compile_cut, 1, refusal)   # no smaller size
+
+
+def _warmup(fed):
+    """Every client's warm-up round from the shared start, as
+    ``profile_clients`` runs it (the default split: one bucket)."""
+    from repro.data.pipeline import infinite_batches
+    clients = list(range(fed.fed.n_clients))
+    iters = {n: infinite_batches(fed.data[n].tokens, fed.data[n].labels,
+                                 fed.fed.batch_size, seed=fed.fed.seed + n)
+             for n in clients}
+    return fed.group_steps(clients, fed.lora0, fed.fed.local_warmup_steps,
+                           iters, use_split=False)
+
+
+def test_cut_warmup_matches_whole_and_is_counted(x64_feds, monkeypatch):
+    """A warm-up the compiler will not take whole goes in cuts of the
+    largest size it takes, with one host sync per call; each client's
+    adapters and loss are what the whole program gives."""
+    from repro import telemetry as tm
+    fb, _ = x64_feds
+    with jax.enable_x64(True):
+        whole = _warmup(fb)
+        refuse_above(monkeypatch, 2)
+        monkeypatch.setattr(fb.engine, "_caps", {})
+        before = sum(fb.engine.compile_cache_sizes().values())
+        with tm.session() as tel:
+            cut = _warmup(fb)
+            again = _warmup(fb)
+    n = fb.fed.n_clients                  # 6: cuts of 2, 2 and 2
+    for res in (cut, again):
+        for c in range(n):
+            # each client is its own row of a vmapped program: the cut
+            # changes only the program's width, which may reorder XLA's
+            # float64 sums (1e-16), and two steps at lr 1e-4 of this
+            # chaotic map (parameter-Lipschitz ~1e5) grow that ~10x a step
+            assert abs(res[c][1] - whole[c][1]) <= 1e-10
+            assert _max_tree_diff(res[c][0], whole[c][0]) <= 1e-10
+    # refused once, whole at 6, then 5, 4 and 3 in the fit (no device
+    # memory limit on the CPU), and kept: the second call cuts at once
+    refusals = tel.counters_by_name("engine.compile_refusals")
+    assert sorted(int(k.split("bucket=")[1].split(",")[0])
+                  for k in refusals) == [3, 4, 5, 6]
+    assert all(v == 1.0 for v in refusals.values())
+    assert sum(tel.counters_by_name("engine.cut_dispatches").values()) == 6
+    assert all("size=2" in k for k in
+               tel.counters_by_name("engine.cut_dispatches"))
+    fits = {k: v for k, v in tel.gauges.items()
+            if k.startswith("engine.fit_clients")}
+    assert list(fits.values()) == [2.0]
+    spans = [s["name"] for s in tel._spans]
+    assert spans.count("engine.fit") == 1
+    assert spans.count("engine.fetch") == 2
+    assert tel.counter("host.syncs", site="engine.fetch") == 2.0
+    # a refused compile is no compile: the counter still equals the cache
+    compiles = sum(tel.counters_by_name("engine.jit_compiles").values())
+    assert compiles == sum(fb.engine.compile_cache_sizes().values()) - before
+
+
+def test_engine_matches_reference_elsa_in_cuts(x64_feds, monkeypatch):
+    """Alg. 1 with every bucket of more than two clients cut: batched ==
+    reference, as whole."""
+    from repro import telemetry as tm
+    fb, fr = x64_feds
+    refuse_above(monkeypatch, 2)
+    monkeypatch.setattr(fb.engine, "_caps", {})
+    with jax.enable_x64(True), tm.session() as tel:
+        _assert_run_parity(fb, fr, "elsa")
+    assert sum(tel.counters_by_name("engine.cut_dispatches").values()) > 0
+
+
+@pytest.mark.parametrize("error", ["runtime_oom", "other"])
+def test_runtime_error_is_not_swallowed(x64_feds, monkeypatch, error):
+    """Only the compiler's refusal is retried smaller: an allocation that
+    fails in a running program, or any other error, ends the call."""
+    fb, _ = x64_feds
+    if error == "runtime_oom":
+        refuse_above(monkeypatch, 0, RUNTIME_OOM)
+        expect = jax.errors.JaxRuntimeError
+    else:
+        from repro.federation.engine import BatchedEngine
+
+        def round_fn(self, split, prox):
+            raise ValueError("not a compile")
+        monkeypatch.setattr(BatchedEngine, "_round_fn", round_fn)
+        expect = ValueError
+    monkeypatch.setattr(fb.engine, "_caps", {})
+    with jax.enable_x64(True), pytest.raises(expect):
+        _warmup(fb)
+    assert fb.engine._caps == {}
